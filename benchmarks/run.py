@@ -11,7 +11,14 @@ path, so no ``PYTHONPATH`` prefix is needed)
 
 ``--workers N`` fans grid suites (churn, multiserver — any suite whose
 ``run`` takes a ``workers`` keyword) out over N processes; results are
-byte-identical at any worker count (benchmarks/par.py).
+byte-identical at any worker count (benchmarks/par.py).  On a TPU it
+refuses: the chip belongs to this one process.
+
+A suite that raises still prints its ``<suite>_ERROR`` row, and the
+driver then exits 1 once every suite has run.  JAX's persistent
+compilation cache is on (``repro.compile_cache``): in
+``JAX_COMPILATION_CACHE_DIR`` when set, else in ``.jax_cache`` at the
+repository root.
 
 ``--json DIR`` additionally writes one machine-readable
 ``BENCH_<suite>.json`` per suite (rows + git SHA + per-suite wall time
@@ -154,8 +161,11 @@ def main(argv=None) -> None:
         return
     names = list(SUITES) if not args.only else args.only.split(",")
     sha = git_sha() if args.json else ""
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
 
     rows = []
+    failed = []
     print("name,us_per_call,derived")
     for name in names:
         t0 = time.time()
@@ -167,8 +177,9 @@ def main(argv=None) -> None:
             kwargs["workers"] = args.workers
         try:
             fn(rows, **kwargs)
-        except Exception as e:   # noqa: BLE001
+        except Exception as e:   # noqa: BLE001 — report, run the rest
             rows.append((f"{name}_ERROR", 0.0, repr(e)[:120]))
+            failed.append(name)
         elapsed = time.time() - t0
         for r in rows[before:]:
             print(f"{r[0]},{r[1]:.4f},{r[2]}")
@@ -178,7 +189,11 @@ def main(argv=None) -> None:
             write_json(Path(args.json), name, rows[before:], elapsed,
                        sha, workers=kwargs.get("workers", 1))
         print(f"# {name} done in {elapsed:.1f}s", file=sys.stderr)
+    if failed:
+        print(f"# suites raised: {','.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -19,7 +19,7 @@ import numpy as np
 
 from repro.api.protocols import WorkloadOutput
 from repro.api.registry import register_workload
-from repro.core.delay_model import DelayModel, fit
+from repro.core.delay_model import DelayModel
 from repro.core.plan import BatchPlan
 from repro.core.quality_model import PowerLawFID, QualityModel
 
@@ -58,6 +58,11 @@ class DiffusionWorkload:
                 cfg, params, exec_engine=self.exec_engine)
         return self._executor
 
+    @property
+    def executor(self):
+        """The ``BatchDenoisingExecutor``, built on first use."""
+        return self._ex()
+
     def default_delay(self) -> DelayModel:
         return DelayModel()                    # paper's RTX-3050 constants
 
@@ -81,9 +86,14 @@ class DiffusionWorkload:
                   batch_sizes: Sequence[int] = (1, 2, 4, 8),
                   reps: int = 3,
                   exec_engine: Optional[str] = None) -> DelayModel:
+        """Fit g(X) = aX + b to the measured curve, with the floors of
+        ``DelayModel.refit`` (a > 0, b > 0): the planners divide by a,
+        and a fast chip's nearly flat curve can fit a slightly negative
+        coefficient."""
         curve = self.measure_delay_curve(key, batch_sizes, reps,
                                          exec_engine=exec_engine)
-        return fit([c[0] for c in curve], [c[1] for c in curve])
+        return DelayModel().refit([c[0] for c in curve],
+                                  [c[1] for c in curve])
 
     def execute(self, plan: BatchPlan, key: Optional[Any] = None,
                 *, timed: bool = False,

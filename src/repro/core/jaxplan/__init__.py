@@ -19,8 +19,8 @@ Layout:
 * ``batched``  — ``plan_many``: the whole T* search vmapped over
   ~10^3 stacked scenarios in one jitted call.
 * ``sharded``  — ``plan_many_sharded``: the scenario axis split across
-  devices with ``shard_map`` (``plan_many(..., devices=...)`` routes
-  here; pmap fallback on older jax).
+  devices with ``jax.shard_map`` (``plan_many(..., devices=...)``
+  routes here).
 * ``optimal``  — the exact DP as a jitted breadth-first sweep.
 
 Equivalence contract: objectives match the NumPy reference within the

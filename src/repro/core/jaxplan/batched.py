@@ -27,6 +27,8 @@ from typing import Optional
 
 import numpy as np
 
+import jax
+
 from repro.core.delay_model import DelayModel
 from repro.core.jaxplan import kernels
 from repro.core.quality_model import PowerLawFID
@@ -129,7 +131,7 @@ def plan_many(tau_prime: np.ndarray, *, delay: DelayModel,
     taup_p, off_p, vd_p, tie, f_thr, lv_p, shift, kb = _pad_stack(
         taup0, off, vd, delay, t_star_max, kernels._bucket(S))
 
-    with kernels.enable_x64():
+    with jax.enable_x64(True):
         best_i, counts, best_q, ms = kernels._plan_many_core(
             taup_p, off_p, vd_p, tie, f_thr, lv_p, shift,
             delay.a, delay.b, quality.alpha, quality.beta,
@@ -210,7 +212,7 @@ def replan_many(tau_prime: np.ndarray, *, delay: DelayModel,
     (taup_p, soff_p, vd_p, dm_p, tie, f_thr, lv_p, lv_ok, shift,
      kb) = _replan_prep(taup0, soff, vd, dm, delay, t_star_max,
                         kernels._bucket(S))
-    with kernels.enable_x64():
+    with jax.enable_x64(True):
         best_i, counts, best_q, ms = kernels._replan_many_core(
             taup_p, soff_p, vd_p, dm_p, tie, f_thr, lv_p, lv_ok, shift,
             delay.a, delay.b, quality.alpha, quality.beta,

@@ -19,7 +19,7 @@ engine spends its time where the work is, scoring L x K x rounds, and
 returns plans constructed by the same code every other engine uses.
 
 All public helpers here take/return NumPy arrays and run the jitted
-core under ``jax.experimental.enable_x64`` so the planner's float64
+core under ``jax.enable_x64(True)`` so the planner's float64
 semantics never leak x64 config into the rest of the process (the
 Pallas denoiser kernels stay float32).  Shapes are padded to
 power-of-two buckets (``_bucket``) so online replans — whose residual
@@ -36,7 +36,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.delay_model import DelayModel
 
@@ -426,7 +425,7 @@ def clustered_counts(taup0: np.ndarray, off: np.ndarray,
     tie = _tie_ranks(taup_p, ids_p)
     f_thr = _f_threshold(taup_p, off_p, lv_p, int(shift), delay.a + delay.b)
     kb = _key_bits(taup_p, off_p, int(shift), delay.a + delay.b)
-    with enable_x64():
+    with jax.enable_x64(True):
         Tc, t = _clustered_jit(taup_p, off_p, lv_p, tie, f_thr, shift,
                                delay.a, delay.b, kb)
     return np.asarray(Tc)[:L, :K], np.asarray(t)[:L]
@@ -444,7 +443,7 @@ def lockstep_counts(taup0: np.ndarray, targets: np.ndarray,
     taup_p = _pad_tail(taup0, Kp, 0.0)
     tg_p = _pad_tail(targets, Kp, 0)
     tg_p = np.pad(tg_p, [(0, Lp - L), (0, 0)], constant_values=0)
-    with enable_x64():
+    with jax.enable_x64(True):
         Tc, t = _lockstep_jit(taup_p, tg_p, delay.a, delay.b)
     return np.asarray(Tc)[:L, :K], np.asarray(t)[:L]
 
@@ -461,7 +460,7 @@ def powerlaw_scores(Tc: np.ndarray, quality, offsets: Optional[np.ndarray],
         else np.asarray(offsets, np.int64)
     dm = np.zeros(K, bool) if doomed is None else np.asarray(doomed, bool)
     vd = np.ones(K, bool) if valid is None else np.asarray(valid, bool)
-    with enable_x64():
+    with jax.enable_x64(True):
         qs = _powerlaw_jit(Tc, off, vd, dm, quality.alpha, quality.beta,
                            quality.gamma, quality.fid_at_zero)
     return np.asarray(qs)
@@ -474,7 +473,7 @@ _powerlaw_jit = jax.jit(_powerlaw_rows)
 # sweep -> masked power-law scoring -> first-best scan, all in a
 # single call.  ``_plan_many_block`` is the unjitted body so the
 # sharded entry point (repro.core.jaxplan.sharded) can wrap the SAME
-# computation in shard_map/pmap per device; ``_plan_many_core`` is the
+# computation in shard_map per device; ``_plan_many_core`` is the
 # single-device jit (the ``plan_many`` core).
 def _plan_many_block(taup0, off, valid, tie, f_thr, levels, shift,
                      a, b, alpha, beta, gamma, fid0, key_bits):

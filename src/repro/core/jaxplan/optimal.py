@@ -32,7 +32,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.delay_model import DelayModel
 from repro.core.jaxplan.kernels import _bucket
@@ -108,7 +107,7 @@ def _search(taus: np.ndarray, delay: DelayModel, quality: QualityModel
         st_p[:N] = states
         valid = np.zeros(Np, dtype=bool)
         valid[:N] = True
-        with enable_x64():
+        with jax.enable_x64(True):
             stop_v, children, feas = _expand_jit(
                 st_p, valid, taus, group, fid_table, np.int64(depth),
                 a, b)

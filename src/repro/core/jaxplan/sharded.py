@@ -24,11 +24,6 @@ rows are fully independent, so the split is embarrassingly parallel:
   (tests/test_jaxplan_sharded.py enforces it at device counts 1/2/8);
 * compiled programs are cached per (device tuple, radix key bits), so
   repeated replan ticks at a stable fleet size pay compilation once.
-
-Where ``shard_map`` is unavailable (older jax), the module falls back
-to a ``pmap`` of the same block over a leading device axis — same
-padding, same results; ``_BACKEND`` records which path is active and
-the tests exercise the fallback by pinning it.
 """
 
 from __future__ import annotations
@@ -39,20 +34,13 @@ from typing import Optional, Sequence, Union
 import numpy as np
 
 import jax
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.delay_model import DelayModel
 from repro.core.jaxplan import kernels
 from repro.core.jaxplan.batched import (PlanManyResult, _check_inputs,
                                         _pad_stack, _replan_prep)
 from repro.core.quality_model import PowerLawFID
-
-try:
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
-    _BACKEND = "shard_map"
-except ImportError:                       # pragma: no cover - old jax
-    shard_map = Mesh = P = None
-    _BACKEND = "pmap"
 
 #: scenarios-per-device type of the ``devices=`` knob
 Devices = Union[None, int, Sequence]
@@ -81,53 +69,40 @@ def resolve_devices(devices: Devices = None):
 
 
 @lru_cache(maxsize=None)
-def _sharded_fn(devs: tuple, key_bits: int, backend: str):
-    """The compiled sharded search for one device set: shard_map (or
-    the pmap fallback) of ``kernels._plan_many_block`` with the
-    scenario axis split across ``devs``.  Cached so replan ticks at a
-    stable fleet size reuse one executable."""
+def _sharded_fn(devs: tuple, key_bits: int):
+    """The compiled sharded search for one device set: ``shard_map`` of
+    ``kernels._plan_many_block`` with the scenario axis split across
+    ``devs``.  Cached so replan ticks at a stable fleet size reuse one
+    executable."""
     block = partial(kernels._plan_many_block, key_bits=key_bits)
-    if backend == "shard_map" and shard_map is not None:
-        mesh = Mesh(np.array(devs), ("s",))
-        sharded = P("s")
-        fn = shard_map(
-            block, mesh=mesh,
-            in_specs=(sharded, sharded, sharded, sharded, sharded,
-                      P(None), P(), P(), P(), P(), P(), P(), P()),
-            out_specs=(sharded, sharded, sharded, sharded),
-            # the block is replication-free by construction (every
-            # output is P("s")-sharded); the checker has no rule for
-            # lax.while_loop, so it must be told rather than asked
-            check_rep=False)
-        return jax.jit(fn), "shard_map"
-    # pmap fallback: same block over an explicit leading device axis
-    fn = jax.pmap(block, devices=devs,
-                  in_axes=(0, 0, 0, 0, 0, None, None, None, None,
-                           None, None, None, None))
-    return fn, "pmap"
+    sharded = P("s")
+    fn = jax.shard_map(
+        block, mesh=Mesh(np.array(devs), ("s",)),
+        in_specs=(sharded, sharded, sharded, sharded, sharded,
+                  P(None), P(), P(), P(), P(), P(), P(), P()),
+        out_specs=(sharded, sharded, sharded, sharded),
+        # the block is replication-free by construction (every output
+        # is P("s")-sharded); the checker has no rule for
+        # lax.while_loop, so it must be told rather than asked
+        check_vma=False)
+    return jax.jit(fn)
 
 
 @lru_cache(maxsize=None)
-def _sharded_replan_fn(devs: tuple, key_bits: int, backend: str):
+def _sharded_replan_fn(devs: tuple, key_bits: int):
     """The compiled sharded REPLAN search (``_replan_many_block``) for
     one device set: same split as ``_sharded_fn`` plus the two extra
     per-row inputs (doomed mask, per-scenario level validity)."""
     block = partial(kernels._replan_many_block, key_bits=key_bits)
-    if backend == "shard_map" and shard_map is not None:
-        mesh = Mesh(np.array(devs), ("s",))
-        sharded = P("s")
-        fn = shard_map(
-            block, mesh=mesh,
-            in_specs=(sharded, sharded, sharded, sharded, sharded,
-                      sharded, P(None), sharded, P(), P(), P(), P(),
-                      P(), P(), P()),
-            out_specs=(sharded, sharded, sharded, sharded),
-            check_rep=False)
-        return jax.jit(fn), "shard_map"
-    fn = jax.pmap(block, devices=devs,
-                  in_axes=(0, 0, 0, 0, 0, 0, None, 0, None, None,
-                           None, None, None, None, None))
-    return fn, "pmap"
+    sharded = P("s")
+    fn = jax.shard_map(
+        block, mesh=Mesh(np.array(devs), ("s",)),
+        in_specs=(sharded, sharded, sharded, sharded, sharded,
+                  sharded, P(None), sharded, P(), P(), P(), P(),
+                  P(), P(), P()),
+        out_specs=(sharded, sharded, sharded, sharded),
+        check_vma=False)
+    return jax.jit(fn)
 
 
 def plan_many_sharded(tau_prime: np.ndarray, *, delay: DelayModel,
@@ -154,21 +129,14 @@ def plan_many_sharded(tau_prime: np.ndarray, *, delay: DelayModel,
     taup_p, off_p, vd_p, tie, f_thr, lv_p, shift, kb = _pad_stack(
         taup0, off, vd, delay, t_star_max, D * rows)
 
-    fn, backend = _sharded_fn(tuple(devs), kb, _BACKEND)
-    args = (taup_p, off_p, vd_p, tie, f_thr)
-    if backend == "pmap":                 # explicit leading device axis
-        args = tuple(a.reshape((D, rows) + a.shape[1:]) for a in args)
-    with kernels.enable_x64():
+    fn = _sharded_fn(tuple(devs), kb)
+    with jax.enable_x64(True):
         best_i, counts, best_q, ms = fn(
-            *args, lv_p, shift, delay.a, delay.b, quality.alpha,
-            quality.beta, quality.gamma, quality.fid_at_zero)
-    best_i, counts = np.asarray(best_i), np.asarray(counts)
+            taup_p, off_p, vd_p, tie, f_thr, lv_p, shift, delay.a,
+            delay.b, quality.alpha, quality.beta, quality.gamma,
+            quality.fid_at_zero)
+    best_i, counts = np.asarray(best_i)[:S], np.asarray(counts)
     best_q, ms = np.asarray(best_q), np.asarray(ms)
-    if backend == "pmap":                 # collapse the device axis
-        best_i = best_i.reshape(-1)
-        counts = counts.reshape((-1,) + counts.shape[2:])
-        best_q, ms = best_q.reshape(-1), ms.reshape(-1)
-    best_i = best_i[:S]
     return PlanManyResult(
         best_level=lv_p[np.maximum(best_i, 0)].astype(np.int64),
         steps=counts[:S, :K],
@@ -199,24 +167,14 @@ def replan_many_sharded(tau_prime: np.ndarray, *, delay: DelayModel,
      kb) = _replan_prep(taup0, soff, vd, dm, delay, t_star_max,
                         D * rows)
 
-    fn, backend = _sharded_replan_fn(tuple(devs), kb, _BACKEND)
-    args = (taup_p, soff_p, vd_p, dm_p, tie, f_thr)
-    lv_ok_arg = lv_ok
-    if backend == "pmap":                 # explicit leading device axis
-        args = tuple(a.reshape((D, rows) + a.shape[1:]) for a in args)
-        lv_ok_arg = lv_ok.reshape((D, rows) + lv_ok.shape[1:])
-    with kernels.enable_x64():
+    fn = _sharded_replan_fn(tuple(devs), kb)
+    with jax.enable_x64(True):
         best_i, counts, best_q, ms = fn(
-            *args, lv_p, lv_ok_arg, shift, delay.a, delay.b,
-            quality.alpha, quality.beta, quality.gamma,
+            taup_p, soff_p, vd_p, dm_p, tie, f_thr, lv_p, lv_ok, shift,
+            delay.a, delay.b, quality.alpha, quality.beta, quality.gamma,
             quality.fid_at_zero)
-    best_i, counts = np.asarray(best_i), np.asarray(counts)
+    best_i, counts = np.asarray(best_i)[:S], np.asarray(counts)
     best_q, ms = np.asarray(best_q), np.asarray(ms)
-    if backend == "pmap":                 # collapse the device axis
-        best_i = best_i.reshape(-1)
-        counts = counts.reshape((-1,) + counts.shape[2:])
-        best_q, ms = best_q.reshape(-1), ms.reshape(-1)
-    best_i = best_i[:S]
     return PlanManyResult(
         best_level=lv_p[np.maximum(best_i, 0)].astype(np.int64),
         steps=counts[:S, :K],

@@ -58,18 +58,19 @@ _SCAN_CHUNKS = (32, 16, 8, 4, 2)
 
 
 def pool_step(step_fn):
-    """Build the gather→step→scatter program body over a latent pool."""
-    def f(pool, idx, t_now, t_next):
-        y = step_fn(pool[idx], t_now, t_next)
+    """Build the gather→step→scatter program body over a latent pool;
+    ``step_fn(params, x, t_now, t_next)`` is the executor's step."""
+    def f(params, pool, idx, t_now, t_next):
+        y = step_fn(params, pool[idx], t_now, t_next)
         return pool.at[idx].set(y)
     return f
 
 
 def pool_scan(step_fn):
     """Scan ``pool_step`` over a ``(C, 2, Bp)`` timestep stack."""
-    def f(pool, idx, ts):
+    def f(params, pool, idx, ts):
         def body(p, t):
-            y = step_fn(p[idx], t[0], t[1])
+            y = step_fn(params, p[idx], t[0], t[1])
             return p.at[idx].set(y), None
         out, _ = jax.lax.scan(body, pool, ts)
         return out
@@ -120,17 +121,18 @@ class BucketedDenoiseSession(DenoiseSession):
     def run_batch(self, ks: List[int], timed: bool = False) -> float:
         idx, t_now, t_next = self._lanes(ks)
         Bp = len(idx)
+        params = self.executor.params
         prog = self.executor.program(
             ("bstep", self._pool_rows, Bp), self._step_prog_body,
-            (self._pool, idx, t_now, t_next), donate=(0,))
+            (params, self._pool, idx, t_now, t_next), donate=(1,))
         dt = 0.0
         if timed:
             t0 = time.perf_counter()
-            pool = prog(self._pool, idx, t_now, t_next)
+            pool = prog(params, self._pool, idx, t_now, t_next)
             pool.block_until_ready()
             dt = time.perf_counter() - t0
         else:
-            pool = prog(self._pool, idx, t_now, t_next)
+            pool = prog(params, self._pool, idx, t_now, t_next)
         self._pool = pool
         self.executor.dispatches += 1
         self._dispatch[Bp] = self._dispatch.get(Bp, 0) + 1
@@ -173,14 +175,17 @@ class BucketedDenoiseSession(DenoiseSession):
             for c in range(C):
                 ts[c, 0, lane] = rem[c]
                 ts[c, 1, lane] = rem[c + 1] if c + 1 < len(rem) else -1
+        params = self.executor.params
         off = 0
         for chunk in _SCAN_CHUNKS:
             while C - off >= chunk:
                 prog = self.executor.program(
                     ("bscan", self._pool_rows, Bp, chunk),
                     self._scan_prog_body,
-                    (self._pool, idx, ts[off:off + chunk]), donate=(0,))
-                self._pool = prog(self._pool, idx, ts[off:off + chunk])
+                    (params, self._pool, idx, ts[off:off + chunk]),
+                    donate=(1,))
+                self._pool = prog(params, self._pool, idx,
+                                  ts[off:off + chunk])
                 self.executor.dispatches += 1
                 key = (Bp, chunk)
                 self._scan_dispatch[key] = \
@@ -237,6 +242,7 @@ def measure_bucketed_curve(executor: BatchDenoisingExecutor, key,
         key, (pool_rows, cfg.image_size, cfg.image_size,
               cfg.in_channels), jnp.float32)
     body = pool_step(executor.step_fn)
+    params = executor.params
     t_mid = executor.T_train // 2
     out = []
     for X in sizes:
@@ -248,15 +254,16 @@ def measure_bucketed_curve(executor: BatchDenoisingExecutor, key,
         t_now[:X] = t_mid
         t_next[:X] = t_mid - 1
         prog = executor.program(("bstep", pool_rows, Bp), body,
-                                (pool, idx, t_now, t_next), donate=(0,))
+                                (params, pool, idx, t_now, t_next),
+                                donate=(1,))
         # warm dispatch (the pool is donated, so rethread it)
-        pool = prog(pool, idx, t_now, t_next)
+        pool = prog(params, pool, idx, t_now, t_next)
         pool.block_until_ready()
         executor.dispatches += 1
         best = float("inf")
         for _ in range(reps):
             t0 = time.perf_counter()
-            pool = prog(pool, idx, t_now, t_next)
+            pool = prog(params, pool, idx, t_now, t_next)
             pool.block_until_ready()
             best = min(best, time.perf_counter() - t0)
             executor.dispatches += 1

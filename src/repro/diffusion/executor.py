@@ -18,7 +18,11 @@ Two execution engines share the ``DenoiseSession`` interface:
     stable plan phases fuse into ``lax.scan`` megasteps.
 
 Step programs are AOT-compiled (``jit(f).lower(...).compile()``) and
-cached on the executor in ``_programs``; compile wall-clock is recorded
+cached on the executor in ``_programs``.  The U-Net weights are the
+programs' first argument, never a closure: closed-over arrays would be
+embedded in every program as constants (~143 MB at the published
+width), bloating compile time and the persistent cache, and they could
+not be compiled from ``jax.eval_shape`` shapes.  Compile wall-clock is recorded
 in ``compile_log`` separately from execution, so timed readings are
 steady-state by construction — ``timed`` mode runs the U-Net exactly
 once per batch (the pre-PR-10 path ran it twice and discarded one).
@@ -31,6 +35,7 @@ per size on the bucketed engine.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -72,14 +77,14 @@ class BatchDenoisingExecutor:
         # longer double-runs the U-Net
         self.dispatches = 0
 
-    def eps_fn(self, x, t):
-        return unet.forward(self.cfg, self.params, x, t)
+    def eps_fn(self, params, x, t):
+        return unet.forward(self.cfg, params, x, t)
 
-    def step_fn(self, x, t_now, t_next):
+    def step_fn(self, params, x, t_now, t_next):
         """One batched DDIM step with per-sample timesteps — the
         function every engine's programs are built from."""
-        return ddim.ddim_step(self.eps_fn, x, t_now, t_next,
-                              self.T_train)
+        return ddim.ddim_step(functools.partial(self.eps_fn, params), x,
+                              t_now, t_next, self.T_train)
 
     def resolve_engine(self, exec_engine: Optional[str] = None) -> str:
         """Call-site override > constructor knob > process default."""
@@ -154,15 +159,15 @@ class BatchDenoisingExecutor:
         t_now = jnp.array([schedule[k][0] for k in ks], jnp.int32)
         t_next = jnp.array([schedule[k][1] for k in ks], jnp.int32)
         prog = self.program(("dstep", len(ks)), self.step_fn,
-                            (x, t_now, t_next))
+                            (self.params, x, t_now, t_next))
         dt = 0.0
         if timed:
             t0 = time.perf_counter()
-            x = prog(x, t_now, t_next)
+            x = prog(self.params, x, t_now, t_next)
             x.block_until_ready()
             dt = time.perf_counter() - t0
         else:
-            x = prog(x, t_now, t_next)
+            x = prog(self.params, x, t_now, t_next)
         self.dispatches += 1
         for i, k in enumerate(ks):
             latents[k] = x[i]
@@ -194,12 +199,12 @@ class BatchDenoisingExecutor:
                 t = jnp.full((X,), self.T_train // 2, jnp.int32)
                 tn = jnp.full((X,), self.T_train // 2 - 1, jnp.int32)
                 prog = self.program(("dstep", int(X)), self.step_fn,
-                                    (x, t, tn))
-                prog(x, t, tn).block_until_ready()   # warm dispatch
+                                    (self.params, x, t, tn))
+                prog(self.params, x, t, tn).block_until_ready()  # warm
                 best = float("inf")
                 for _ in range(reps):
                     t0 = time.perf_counter()
-                    prog(x, t, tn).block_until_ready()
+                    prog(self.params, x, t, tn).block_until_ready()
                     best = min(best, time.perf_counter() - t0)
                 out.append((int(X), best))
         self.last_compile_log = self.compile_log[clog0:]

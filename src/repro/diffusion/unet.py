@@ -11,6 +11,7 @@ kernels/groupnorm_silu Pallas kernel targets on TPU.
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +46,11 @@ def group_norm(x, scale, bias, num_groups: int, eps: float = 1e-6):
     return out.astype(x.dtype)
 
 
+def gn_silu_xla(x, scale, bias, num_groups: int):
+    """GroupNorm+SiLU through XLA: the CPU path and the reference."""
+    return jax.nn.silu(group_norm(x, scale, bias, num_groups))
+
+
 def gn_silu(x, scale, bias, num_groups: int):
     """Fused GroupNorm+SiLU; dispatches to the Pallas kernel on TPU (or
     under REPRO_FORCE_PALLAS=1) — the U-Net's HBM hot spot."""
@@ -54,7 +60,7 @@ def gn_silu(x, scale, bias, num_groups: int):
         from repro.kernels.groupnorm_silu.kernel import groupnorm_silu_pallas
         return groupnorm_silu_pallas(x, scale, bias, num_groups,
                                      interpret=(mode == "interpret"))
-    return jax.nn.silu(group_norm(x, scale, bias, num_groups))
+    return gn_silu_xla(x, scale, bias, num_groups)
 
 
 def timestep_embedding(t, dim: int, max_period: float = 10000.0):
@@ -155,11 +161,11 @@ def schema(cfg: UNetConfig):
 # Forward
 # ---------------------------------------------------------------------------
 
-def _res_block(cfg, p, x, temb):
-    h = gn_silu(x, p["gn1_s"], p["gn1_b"], cfg.num_groups)
+def _res_block(cfg, p, x, temb, norm):
+    h = norm(x, p["gn1_s"], p["gn1_b"], cfg.num_groups)
     h = conv2d(h, p["conv1"])
     h = h + (jax.nn.silu(temb) @ p["temb"])[:, None, None, :]
-    h = gn_silu(h, p["gn2_s"], p["gn2_b"], cfg.num_groups)
+    h = norm(h, p["gn2_s"], p["gn2_b"], cfg.num_groups)
     h = conv2d(h, p["conv2"])
     skip = conv2d(x, p["skip"]) if "skip" in p else x
     return skip + h
@@ -176,9 +182,11 @@ def _attn_block(cfg, p, x):
     return x + out.reshape(B, H, W, C)
 
 
-def forward(cfg: UNetConfig, params, x, t):
+def forward(cfg: UNetConfig, params, x, t, norm=gn_silu):
     """x: (B, H, W, C) noisy images; t: (B,) per-sample timesteps.
-    Returns predicted noise eps, same shape as x."""
+    Returns predicted noise eps, same shape as x.  ``norm`` is the
+    GroupNorm+SiLU implementation (``reference_forward`` passes the XLA
+    one)."""
     temb = timestep_embedding(t, cfg.base_channels)
     temb = jax.nn.silu(temb @ params["temb1"]) @ params["temb2"]
 
@@ -186,7 +194,7 @@ def forward(cfg: UNetConfig, params, x, t):
     skips = [h]
     for level in params["downs"]:
         for blk in level["res"]:
-            h = _res_block(cfg, blk["res"], h, temb)
+            h = _res_block(cfg, blk["res"], h, temb, norm)
             if "attn" in blk:
                 h = _attn_block(cfg, blk["attn"], h)
             skips.append(h)
@@ -194,14 +202,14 @@ def forward(cfg: UNetConfig, params, x, t):
             h = conv2d(h, level["down"], stride=2)
             skips.append(h)
 
-    h = _res_block(cfg, params["mid1"], h, temb)
+    h = _res_block(cfg, params["mid1"], h, temb, norm)
     h = _attn_block(cfg, params["mid_attn"], h)
-    h = _res_block(cfg, params["mid2"], h, temb)
+    h = _res_block(cfg, params["mid2"], h, temb, norm)
 
     for level in params["ups"]:
         for blk in level["res"]:
             h = jnp.concatenate([h, skips.pop()], axis=-1)
-            h = _res_block(cfg, blk["res"], h, temb)
+            h = _res_block(cfg, blk["res"], h, temb, norm)
             if "attn" in blk:
                 h = _attn_block(cfg, blk["attn"], h)
         if "up" in level:
@@ -209,5 +217,14 @@ def forward(cfg: UNetConfig, params, x, t):
             h = jax.image.resize(h, (B, 2 * H, 2 * W, C), "nearest")
             h = conv2d(h, level["up"])
 
-    h = gn_silu(h, params["gn_out_s"], params["gn_out_b"], cfg.num_groups)
+    h = norm(h, params["gn_out_s"], params["gn_out_b"], cfg.num_groups)
     return conv2d(h, params["conv_out"])
+
+
+def reference_forward(cfg: UNetConfig, params, x, t):
+    """Plain float32 reference for ``forward``: the XLA GroupNorm (no
+    Pallas kernel) and every matmul and convolution at full float32
+    precision, whatever the device's default matmul precision is."""
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(functools.partial(forward, cfg, norm=gn_silu_xla))(
+            params, x, t)

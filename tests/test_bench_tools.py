@@ -450,3 +450,31 @@ class TestBenchShim:
             capture_output=True, text=True, timeout=120)
         assert proc.returncode == 0, proc.stderr[-2000:]
         assert proc.stdout.strip() == "ok"
+
+
+class TestDriverFailures:
+    def test_raising_suite_keeps_error_row_and_exits_nonzero(
+            self, monkeypatch, tmp_path, capsys):
+        def broken(rows):
+            rows.append(("broken_partial", 1.0, ""))
+            raise RuntimeError("suite blew up")
+
+        # with the variable set, compile_cache.enable leaves JAX's
+        # config alone, so this process's cache stays as it was
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        monkeypatch.setitem(bench_run.SUITES, "broken", broken)
+        monkeypatch.setitem(bench_run.SUITES, "fine",
+                            lambda rows: rows.append(("fine_ok", 2.0, "")))
+        assert bench_run.main(["--only", "broken,fine"]) == 1
+        out = capsys.readouterr().out
+        assert "broken_ERROR,0.0000,RuntimeError('suite blew up')" in out
+        assert "fine_ok,2.0000," in out        # later suites still run
+        assert bench_run.main(["--only", "fine"]) == 0
+
+    def test_parallel_map_refuses_fanout_on_tpu(self, monkeypatch):
+        import jax
+        from benchmarks import par
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="workers=1"):
+            par.parallel_map(abs, [-1, -2], workers=2)
+        assert par.parallel_map(abs, [-1, -2], workers=1) == [1, 2]

@@ -44,6 +44,21 @@ class TestUNet:
         eps = unet.forward(SMOKE, params, x, t)
         assert float(jnp.abs(eps[0] - eps[1]).max()) > 1e-4
 
+    def test_reference_forward_matches_forward(self, unet_params,
+                                               monkeypatch):
+        """The plain float32 reference (XLA GroupNorm, highest matmul
+        precision) agrees with the served forward pass; with the Pallas
+        kernel forced on in interpret mode it still does, so the chip's
+        eps check compares like with like."""
+        x = jax.random.normal(jax.random.PRNGKey(4), (3, 16, 16, 3))
+        t = jnp.array([999, 400, 0], jnp.int32)
+        ref = unet.reference_forward(SMOKE, unet_params, x, t)
+        for force in ("0", "1"):
+            monkeypatch.setenv("REPRO_FORCE_PALLAS", force)
+            got = unet.forward(SMOKE, unet_params, x, t)
+            rel = float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
+            assert rel < 1e-5, (force, rel)
+
     def test_mixed_batch_equals_individual(self, unet_params):
         """Batch denoising invariant: running two services in one batch
         gives the same result as running them separately (Fig. 1a's

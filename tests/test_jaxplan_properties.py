@@ -11,6 +11,7 @@ within a couple of jit shape buckets — the suite pays a handful of
 compiles, not one per example.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -120,7 +121,7 @@ def test_radix_select_matches_full_sort_on_tie_heavy_keys(data):
     # every batch size x_n in 1..K for every row, as one stacked call
     key_all = np.repeat(key_np, K, axis=0)        # (L*K, K)
     x_all = np.tile(np.arange(1, K + 1, dtype=np.int64), L)
-    with kernels.enable_x64():
+    with jax.enable_x64(True):
         key = jnp.asarray(key_all)
         x_n = jnp.asarray(x_all)
         sel = np.asarray(kernels._select_kth_key(key, x_n, key_bits))

@@ -2,7 +2,7 @@
 (ISSUE 7 tentpole): ``plan_many`` with the scenario axis sharded
 across host devices must match the single-device call and the vec
 loop within the documented 1e-9 mean-FID tolerance — across device
-counts, non-divisible S, empty shards, and the pmap fallback.
+counts, non-divisible S and empty shards.
 
 The fast CI matrix exports
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so every
@@ -138,19 +138,6 @@ def test_resolve_devices_contract():
         sharded.resolve_devices(N_DEV + 1)
     with pytest.raises(ValueError):
         sharded.resolve_devices([])
-
-
-@needs_devices(2)
-def test_pmap_fallback_matches(monkeypatch):
-    """Pinning the pmap backend (what older jax falls back to) gives
-    the same plans as shard_map."""
-    taus = _instance(13, K=5, seed=11)
-    via_smap = sharded.plan_many_sharded(taus, delay=DELAY,
-                                         quality=QUALITY, devices=2)
-    monkeypatch.setattr(sharded, "_BACKEND", "pmap")
-    via_pmap = sharded.plan_many_sharded(taus, delay=DELAY,
-                                         quality=QUALITY, devices=2)
-    _assert_matches(via_smap, via_pmap)
 
 
 @needs_devices(2)

@@ -164,6 +164,10 @@ def test_ssd_chunked_per_head_bc():
 
 @pytest.mark.parametrize("B,H,W,C,G", [
     (2, 8, 8, 32, 8), (1, 16, 16, 24, 6), (3, 4, 4, 16, 16),
+    # H*W not a multiple of 8 (sublane padding); published channel
+    # widths with 12- and 4-channel groups
+    (2, 5, 5, 384, 32), (1, 3, 7, 128, 32), (2, 4, 4, 384, 32),
+    (1, 8, 8, 512, 32),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_groupnorm_silu_sweep(B, H, W, C, G, dtype):
@@ -175,3 +179,49 @@ def test_groupnorm_silu_sweep(B, H, W, C, G, dtype):
     want = groupnorm_silu_ref(x, s, b, G)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
+
+
+def test_groupnorm_silu_offset_statistics():
+    """Per-group statistics stay exact when groups sit at very different
+    offsets and scales (a kernel mixing channels across groups, or
+    losing precision in the one-hot group matmuls, fails here)."""
+    B, H, W, C, G = 2, 4, 4, 128, 32
+    x = jax.random.normal(jax.random.PRNGKey(7), (B, H, W, C))
+    offs = jnp.repeat(jnp.arange(G, dtype=jnp.float32) * 100.0, C // G)
+    gain = jnp.repeat(1.0 + jnp.arange(G, dtype=jnp.float32), C // G)
+    x = x * gain + offs
+    s = jnp.ones((C,))
+    b = jnp.zeros((C,))
+    got = groupnorm_silu_pallas(x, s, b, G, interpret=True)
+    want = groupnorm_silu_ref(x, s, b, G)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
+
+
+def test_use_pallas_raises_on_backend_error(monkeypatch):
+    """A backend that fails to initialize is an error, not a silent
+    switch to the reference path."""
+    from repro import kernels
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        kernels.use_pallas()
+
+
+def test_force_pallas_applies_only_off_the_chip(monkeypatch):
+    from repro import kernels
+
+    class _Dev:
+        def __init__(self, platform):
+            self.platform = platform
+
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("tpu")])
+    assert kernels.use_pallas() == "tpu"
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("cpu")])
+    assert kernels.use_pallas() == "interpret"
+    monkeypatch.delenv("REPRO_FORCE_PALLAS")
+    assert kernels.use_pallas() == "ref"
