@@ -31,6 +31,7 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
+from repro import spans
 from repro.api.protocols import WorkloadOutput
 from repro.api.registry import (ALLOCATORS, EXECUTORS, SCHEDULERS,
                                 WORKLOADS, register_executor)
@@ -102,17 +103,18 @@ def execute_plan(scenario, plan: BatchPlan, alloc, workload=None, *,
     if exec_engine is not None:
         executor_kwargs = dict(executor_kwargs or {})
         executor_kwargs.setdefault("exec_engine", exec_engine)
-    session = make_session(workload, plan, key, executor=executor,
-                           executor_kwargs=executor_kwargs)
-    loop = ExecutionLoop(
-        scenario, plan, alloc, session, delay=delay, quality=quality,
-        scheduler=SCHEDULERS.resolve(scheduler),
-        allocator=ALLOCATORS.resolve(allocator),
-        mode=mode, window=window, drift_tol=drift_tol,
-        min_batches=min_batches, max_replans=max_replans,
-        headroom=headroom, validate=validate, engine=engine,
-        exec_engine=(executor_kwargs or {}).get("exec_engine"))
-    return loop.run()
+    with spans.span(spans.EXECUTE):
+        session = make_session(workload, plan, key, executor=executor,
+                               executor_kwargs=executor_kwargs)
+        loop = ExecutionLoop(
+            scenario, plan, alloc, session, delay=delay, quality=quality,
+            scheduler=SCHEDULERS.resolve(scheduler),
+            allocator=ALLOCATORS.resolve(allocator),
+            mode=mode, window=window, drift_tol=drift_tol,
+            min_batches=min_batches, max_replans=max_replans,
+            headroom=headroom, validate=validate, engine=engine,
+            exec_engine=(executor_kwargs or {}).get("exec_engine"))
+        return loop.run()
 
 
 def execute_report(report, workload=None, *, mode: str = "closed",

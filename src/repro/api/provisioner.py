@@ -23,6 +23,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.api.base import BaseProvisioner, report_dict
 from repro.api.protocols import WorkloadOutput
 from repro.api.registry import (ALLOCATORS, SCHEDULERS, WORKLOADS,
@@ -173,7 +174,7 @@ class Provisioner(BaseProvisioner):
     def allocate(self) -> np.ndarray:
         """P1: bandwidth allocation under the current delay/quality."""
         from repro.core import arrays
-        with arrays.engine_scope(self.engine):
+        with spans.span(spans.ALLOCATE), arrays.engine_scope(self.engine):
             return np.asarray(self.allocator(
                 self.scenario, self.scheduler, self.delay, self.quality,
                 **self.allocator_kwargs))
@@ -181,7 +182,7 @@ class Provisioner(BaseProvisioner):
     def plan(self, alloc: np.ndarray) -> Tuple[Dict[int, float], BatchPlan]:
         """P2: generation budgets + batch plan under an allocation."""
         from repro.core import arrays
-        with arrays.engine_scope(self.engine):
+        with spans.span(spans.PLAN), arrays.engine_scope(self.engine):
             return make_plan(self.scenario, alloc, self.scheduler,
                              self.delay, self.quality)
 
@@ -193,6 +194,7 @@ class Provisioner(BaseProvisioner):
         return self.delay
 
     # -- one-call end-to-end --------------------------------------------
+    @spans.provision()
     def run(self, key=None, *, execute=None, timed: bool = False,
             calibrate: bool = False, refit: bool = False,
             validate: bool = True) -> ProvisionReport:
@@ -228,8 +230,10 @@ class Provisioner(BaseProvisioner):
         alloc = self.allocate()
         tp, plan = self.plan(alloc)
         if validate:
-            plan.validate(gen_deadlines=tp)
-        sim = simulate(self.scenario, alloc, plan, self.quality)
+            with spans.span(spans.VALIDATE):
+                plan.validate(gen_deadlines=tp)
+        with spans.span(spans.SIMULATE):
+            sim = simulate(self.scenario, alloc, plan, self.quality)
         out = WorkloadOutput(content=None)
         execution = None
         if mode is True and self.workload is not None:

@@ -59,6 +59,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.delay_model import DelayModel
 from repro.core.plan import BatchPlan
 
@@ -549,12 +550,14 @@ def stacking_vec(services, tau_prime: Dict[int, float], delay: DelayModel,
     arr = ServiceArrays.build(ids, tau_prime)
     levels = np.arange(1, t_star_max + 1, dtype=np.int64)
     hist: list = []
-    Tc, _, _, _ = _clustered_rounds(arr.ids, arr.tau_prime, arr.offsets,
-                                    delay, levels, history=hist)
-
-    best_i, _ = first_best(Tc, quality)
+    with spans.span(spans.PLAN_CLUSTERED):
+        Tc, _, _, _ = _clustered_rounds(arr.ids, arr.tau_prime,
+                                        arr.offsets, delay, levels,
+                                        history=hist)
+        best_i, _ = first_best(Tc, quality)
     assert best_i >= 0
-    batches, starts = _replay_clustered(arr.ids, best_i, hist, delay)
+    with spans.span(spans.PLAN_REPLAY):
+        batches, starts = _replay_clustered(arr.ids, best_i, hist, delay)
     steps = {int(k): int(c) for k, c in zip(arr.ids, Tc[best_i])}
     return BatchPlan(batches=batches, start_times=starts,
                      steps_completed=steps, delay=delay)

@@ -38,6 +38,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import spans
 from repro.core import arrays
 from repro.core.bandwidth import make_plan
 from repro.core.delay_model import DelayModel, RollingDelayFit
@@ -309,7 +310,8 @@ class ExecutionLoop:
         while self.i < len(self.batches):
             ks = [k for k, _ in self.batches[self.i]]
             predicted = self.delay.g(len(ks))
-            dt = float(self.session.run_batch(ks, timed=True))
+            with spans.span(spans.BATCH, size=len(ks)):
+                dt = float(self.session.run_batch(ks, timed=True))
             t_end = t + dt
             for k in ks:
                 st = self.states[k]
@@ -333,7 +335,8 @@ class ExecutionLoop:
                     and len(self._drift) >= self.min_batches
                     and self.replans < self.max_replans
                     and float(np.mean(self._drift)) > self.drift_tol):
-                self._replan(t)
+                with spans.span(spans.REPLAN):
+                    self._replan(t)
         return self._finalize(t)
 
     def _replan(self, t: float) -> None:
@@ -395,7 +398,8 @@ class ExecutionLoop:
             st = self.states[k]
             if st.steps_done > 0 and not st.gen_complete:
                 self._complete(st, t, self.alloc_map[k])
-        content = self.session.finish()
+        with spans.span(spans.FINISH):
+            content = self.session.finish()
         if self.fit.ready:
             # final refit from the telemetry window, in both modes —
             # result.delay always reflects the measured hardware

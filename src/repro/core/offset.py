@@ -56,6 +56,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import spans
 from repro.core import arrays
 from repro.core.delay_model import DelayModel
 from repro.core.online import _OffsetQuality
@@ -272,30 +273,33 @@ class StackingOffset:
         levels = np.arange(1, level_max + 1, dtype=np.int64)
         # family 1 — Algorithm 1 clustered on TOTAL counts
         h1: list = []
-        Tc1, ms1, _, _ = arrays._clustered_rounds(
-            arr.ids, arr.tau_prime, arr.offsets, delay, levels,
-            history=h1)
-        for i, q in enumerate(arrays.score_rows(Tc1, oq).tolist()):
-            consider(q, float(ms1[i]), ("clustered", i))
+        with spans.span(spans.PLAN_CLUSTERED):
+            Tc1, ms1, _, _ = arrays._clustered_rounds(
+                arr.ids, arr.tau_prime, arr.offsets, delay, levels,
+                history=h1)
+            for i, q in enumerate(arrays.score_rows(Tc1, oq).tolist()):
+                consider(q, float(ms1[i]), ("clustered", i))
 
         # family 2 — lockstep water-filling over the total-step level
         targets = np.maximum(levels[:, None] - arr.offsets[None, :], 0)
         nonzero = targets.any(axis=1)
         h2: list = []
-        Tc2, ms2, _, _ = arrays._lockstep_rounds(
-            arr.ids, arr.tau_prime, targets, delay, history=h2)
-        for i, q in enumerate(arrays.score_rows(Tc2, oq).tolist()):
-            if nonzero[i]:
-                consider(q, float(ms2[i]), ("lockstep", i))
+        with spans.span(spans.PLAN_LOCKSTEP):
+            Tc2, ms2, _, _ = arrays._lockstep_rounds(
+                arr.ids, arr.tau_prime, targets, delay, history=h2)
+            for i, q in enumerate(arrays.score_rows(Tc2, oq).tolist()):
+                if nonzero[i]:
+                    consider(q, float(ms2[i]), ("lockstep", i))
 
         # family 3 — shared-NEW-horizon Algorithm 1 candidates
         levels3 = np.arange(1, t_new_max + 1, dtype=np.int64)
         h3: list = []
-        Tc3, ms3, _, _ = arrays._clustered_rounds(
-            arr.ids, arr.tau_prime, np.zeros(arr.K, dtype=np.int64),
-            delay, levels3, history=h3)
-        for i, q in enumerate(arrays.score_rows(Tc3, oq).tolist()):
-            consider(q, float(ms3[i]), ("shared", i))
+        with spans.span(spans.PLAN_SHARED):
+            Tc3, ms3, _, _ = arrays._clustered_rounds(
+                arr.ids, arr.tau_prime, np.zeros(arr.K, dtype=np.int64),
+                delay, levels3, history=h3)
+            for i, q in enumerate(arrays.score_rows(Tc3, oq).tolist()):
+                consider(q, float(ms3[i]), ("shared", i))
 
         pick = state["pick"]
         if pick is None:
@@ -309,7 +313,8 @@ class StackingOffset:
             counts, hist, replay = Tc2[i], h2, arrays._replay_lockstep
         else:
             counts, hist, replay = Tc3[i], h3, arrays._replay_clustered
-        batches, starts = replay(arr.ids, i, hist, delay)
+        with spans.span(spans.PLAN_REPLAY):
+            batches, starts = replay(arr.ids, i, hist, delay)
         steps = {int(k): int(c) for k, c in zip(arr.ids, counts)}
         return BatchPlan(batches=batches, start_times=starts,
                          steps_completed=steps, delay=delay)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.execution import shape_bucket
 from repro.diffusion.executor import BatchDenoisingExecutor, \
     DenoiseSession
@@ -59,22 +60,24 @@ _SCAN_CHUNKS = (32, 16, 8, 4, 2)
 
 def pool_step(step_fn):
     """Build the gather→step→scatter program body over a latent pool;
-    ``step_fn(params, x, t_now, t_next)`` is the executor's step."""
-    def f(params, pool, idx, t_now, t_next):
+    ``step_fn(params, x, t_now, t_next)`` is the executor's step.  The
+    body's name, ``bucketed_step``, names its program in a device trace
+    (``jit_bucketed_step``)."""
+    def bucketed_step(params, pool, idx, t_now, t_next):
         y = step_fn(params, pool[idx], t_now, t_next)
         return pool.at[idx].set(y)
-    return f
+    return bucketed_step
 
 
 def pool_scan(step_fn):
     """Scan ``pool_step`` over a ``(C, 2, Bp)`` timestep stack."""
-    def f(params, pool, idx, ts):
+    def bucketed_scan(params, pool, idx, ts):
         def body(p, t):
             y = step_fn(params, p[idx], t[0], t[1])
             return p.at[idx].set(y), None
         out, _ = jax.lax.scan(body, pool, ts)
         return out
-    return f
+    return bucketed_scan
 
 
 class BucketedDenoiseSession(DenoiseSession):
@@ -119,20 +122,21 @@ class BucketedDenoiseSession(DenoiseSession):
         return idx, t_now, t_next
 
     def run_batch(self, ks: List[int], timed: bool = False) -> float:
-        idx, t_now, t_next = self._lanes(ks)
+        with spans.span(spans.SESSION_LANES):
+            idx, t_now, t_next = self._lanes(ks)
         Bp = len(idx)
         params = self.executor.params
-        prog = self.executor.program(
-            ("bstep", self._pool_rows, Bp), self._step_prog_body,
-            (params, self._pool, idx, t_now, t_next), donate=(1,))
-        dt = 0.0
-        if timed:
+        with spans.span(spans.SESSION_DISPATCH):
+            prog = self.executor.program(
+                ("bstep", self._pool_rows, Bp), self._step_prog_body,
+                (params, self._pool, idx, t_now, t_next), donate=(1,))
             t0 = time.perf_counter()
             pool = prog(params, self._pool, idx, t_now, t_next)
-            pool.block_until_ready()
+        dt = 0.0
+        if timed:
+            with spans.span(spans.SESSION_WAIT):
+                pool.block_until_ready()
             dt = time.perf_counter() - t0
-        else:
-            pool = prog(params, self._pool, idx, t_now, t_next)
         self._pool = pool
         self.executor.dispatches += 1
         self._dispatch[Bp] = self._dispatch.get(Bp, 0) + 1

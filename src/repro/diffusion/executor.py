@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.ddim_cifar10 import UNetConfig
 from repro.core.execution import EXEC_ENGINES, exec_engine_default
 from repro.core.plan import BatchPlan
@@ -103,8 +104,9 @@ class BatchDenoisingExecutor:
         prog = self._programs.get(key)
         if prog is None:
             t0 = time.perf_counter()
-            prog = jax.jit(build, donate_argnums=donate) \
-                .lower(*example_args).compile()
+            with spans.span(spans.COMPILE, kind=key[0]):
+                prog = jax.jit(build, donate_argnums=donate) \
+                    .lower(*example_args).compile()
             self.compile_log.append((key, time.perf_counter() - t0))
             self._programs[key] = prog
         return prog
@@ -117,11 +119,12 @@ class BatchDenoisingExecutor:
         (``repro.core.execution``) can observe wall-clock and retarget
         remaining schedules between batches."""
         eng = self.resolve_engine(exec_engine)
-        if eng == "bucketed":
-            # imported lazily: bucketed.py subclasses DenoiseSession
-            from repro.diffusion.bucketed import BucketedDenoiseSession
-            return BucketedDenoiseSession(self, plan, key)
-        return DenoiseSession(self, plan, key)
+        with spans.span(spans.SESSION_OPEN):
+            if eng == "bucketed":
+                # imported lazily: bucketed.py subclasses DenoiseSession
+                from repro.diffusion.bucketed import BucketedDenoiseSession
+                return BucketedDenoiseSession(self, plan, key)
+            return DenoiseSession(self, plan, key)
 
     def run(self, plan: BatchPlan, key, timed: bool = False,
             exec_engine: Optional[str] = None

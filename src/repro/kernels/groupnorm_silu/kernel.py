@@ -75,6 +75,7 @@ def groupnorm_silu_pallas(x, scale, bias, num_groups: int,
         ],
         out_specs=pl.BlockSpec((1, HW, C), lambda b: (b, 0, 0)),
         interpret=interpret,
+        name="groupnorm_silu",
     )(x.reshape(B, HW, C), scale.reshape(1, C).astype(jnp.float32),
       bias.reshape(1, C).astype(jnp.float32))
     return out.reshape(B, H, W, C)
