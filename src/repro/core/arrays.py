@@ -20,6 +20,9 @@ K services, one python-level loop iteration per batch *round* (shared
 by every candidate) instead of one per (candidate, round, service).
 ``Te``/``Tp`` tables, the priority-cluster split, the packing caps and
 the unaffordable-member drop loop are all computed as whole-array ops.
+Levels finish rounds apart (a level with no active service is done),
+so the clustered sweep gathers its state down to the live levels once
+enough have finished; its per-round snapshots cover those levels only.
 
 Bit-identical by construction: the kernels perform the same float64
 operations in the same order as the scalar loops (one subtraction per
@@ -66,6 +69,9 @@ from repro.core.plan import BatchPlan
 # int64 sentinel pushing inactive services past every real Tp in the
 # (Tp, tau', id) lexsort; far below int64 overflow when summed with keys
 _TP_INF = np.int64(1) << 62
+# the clustered sweep compacts its working rows to the live ones once
+# fewer than this share of them are live (``_clustered_rounds``)
+_COMPACT_BELOW = 0.8
 
 
 # -------------------------------------------------------------------------
@@ -229,10 +235,22 @@ def _clustered_rounds(ids: np.ndarray, taup0: np.ndarray, off: np.ndarray,
     ``(L, K)`` completed counts, ``makespan`` ``(L,)``.  ``record=True``
     (single level only) additionally materializes the batch list in the
     scalar pass's exact order (sorted-cluster sequence).  ``history``
-    (a caller-owned list) collects per-round ``(order, packed, x_n,
+    (a caller-owned list) collects per-round ``(rows, key, packed,
     has_batch)`` snapshots so the outer searches can replay ANY row's
     batch list afterwards (``_replay_clustered``) without re-running a
     pass for the winning candidate.
+
+    The rounds compute only the *working* rows, ``rows`` (sorted output
+    row indices).  A row with no active service is finished: it packs
+    nothing, so its ``Tc`` and clock never change again.  Once fewer
+    than ``_COMPACT_BELOW`` of the working rows are live, the finished
+    ones are written to the output and the working state (``taup``,
+    ``Tc``, ``active``, ``t`` and the per-level constants) is gathered
+    down to the live rows, under a ``repro.plan.compact`` span (stats
+    ``kept``, ``of``).  Each row's values are computed by the same
+    operations as without compaction; a single level never compacts.
+    Each snapshot covers the working rows of its round; its ``rows``
+    names them.
     """
     a, b = delay.a, delay.b
     levels = np.asarray(levels, dtype=np.int64)
@@ -279,8 +297,27 @@ def _clustered_rounds(ids: np.ndarray, taup0: np.ndarray, off: np.ndarray,
                      np.int64(-1))
     batches: List[List[Tuple[int, int]]] = []
     starts: List[float] = []
+    rows = np.arange(L)                  # working row -> output row
+    Tc_out = np.zeros((L, K), dtype=np.int64)
+    t_out = np.zeros(L, dtype=np.float64)
 
-    while active.any():
+    while True:
+        n_active = active.sum(axis=-1)
+        live = n_active > 0
+        n_live = int(np.count_nonzero(live))
+        if n_live == 0:
+            break
+        if n_live < _COMPACT_BELOW * rows.size:
+            with spans.span(spans.PLAN_COMPACT, kept=n_live, of=rows.size):
+                done = ~live
+                Tc_out[rows[done]] = Tc[done]
+                t_out[rows[done]] = t[done]
+                rows, n_active = rows[live], n_active[live]
+                taup, Tc, active, t = (taup[live], Tc[live], active[live],
+                                       t[live])
+                F_thr, b_lv, a_lv, lv_pos = (F_thr[live], b_lv[live],
+                                             a_lv[live], lv_pos[live])
+
         # ---- clustering (Eqs. 15-18, offset-shifted) ---------------------
         # T^e: tasks completable in the remaining budget on dedicated
         # batches — int() truncation == floor for the (positive) budgets
@@ -290,7 +327,6 @@ def _clustered_rounds(ids: np.ndarray, taup0: np.ndarray, off: np.ndarray,
         Tp = off2 + Tc + Te
         key = np.where(active, Tp * M + tie, _TP_INF)
 
-        n_active = active.sum(axis=-1)
         F = key <= F_thr[:, None]
         n_F = F.sum(axis=-1)
 
@@ -346,7 +382,7 @@ def _clustered_rounds(ids: np.ndarray, taup0: np.ndarray, off: np.ndarray,
                             for j in members])
             starts.append(float(t[0]))
         if history is not None:
-            history.append((key, packed, has_batch))
+            history.append((rows, key, packed, has_batch))
         np.add(t, g, out=t, where=has_batch)
         adv = active & has_batch[:, None]    # wall clock advances for all
         np.subtract(taup, g[:, None], out=taup, where=adv)     # (Eq. 15)
@@ -354,7 +390,9 @@ def _clustered_rounds(ids: np.ndarray, taup0: np.ndarray, off: np.ndarray,
         # services that can no longer fit even a dedicated batch are done
         active &= taup + 1e-12 >= g1
 
-    return Tc, t, batches, starts
+    Tc_out[rows] = Tc
+    t_out[rows] = t
+    return Tc_out, t_out, batches, starts
 
 
 def _lockstep_rounds(ids: np.ndarray, taup0: np.ndarray,
@@ -414,21 +452,28 @@ def _replay_clustered(ids: np.ndarray, w: int, history: list,
                       delay: DelayModel):
     """Reconstruct row ``w``'s batch list from a clustered sweep's
     per-round snapshots — the same (batches, start_times) the scalar
-    pass records, without re-running the pass."""
+    pass records, without re-running the pass.  Row ``w`` sits at its
+    place in each snapshot's ``rows``; the first snapshot without it
+    comes after the row finished, so the replay stops there."""
     a, b = delay.a, delay.b
     Tc = np.zeros(ids.size, dtype=np.int64)
     batches: List[List[Tuple[int, int]]] = []
     starts: List[float] = []
     t = 0.0
-    for key, packed, has_batch in history:
-        if not has_batch[w]:
+    seen, r = None, 0
+    for rows, key, packed, has_batch in history:
+        if rows is not seen:            # a compaction renumbered rows
+            seen, r = rows, int(np.searchsorted(rows, w))
+            if r == rows.size or rows[r] != w:
+                break
+        if not has_batch[r]:
             continue
-        idx = np.flatnonzero(packed[w])
-        members = idx[np.argsort(key[w, idx])]
+        idx = np.flatnonzero(packed[r])
+        members = idx[np.argsort(key[r, idx])]
         batches.append([(int(ids[j]), int(Tc[j])) for j in members])
         starts.append(t)
         t += a * len(members) + b
-        Tc[packed[w]] += 1
+        Tc[packed[r]] += 1
     return batches, starts
 
 

@@ -140,7 +140,8 @@ def _clustered_chunk(taup0, off, levels, tie, f_thr, shift, a, b,
     levels: ``(taup0 (K,), off (K,), levels (Lc,), tie (K,), f_thr
     (Lc,))`` -> ``(Tc (Lc, K) int64, makespan (Lc,) float64)``.
     Literal port of ``arrays._clustered_rounds`` minus history
-    recording, with the per-round full sort replaced by the
+    recording and live-level compaction (the fixed-shape chunk runs
+    every level to the end), with the per-round full sort replaced by the
     decision-identical radix selection (``key_bits`` is the static
     trip count)."""
     L, K = levels.shape[0], taup0.shape[0]

@@ -10,8 +10,10 @@ round, per stage or per batch, never per service.  They nest so:
       repro.allocate             P1, bandwidth allocation
       repro.plan                 P2, generation budgets and batch plan
         repro.plan.clustered     Algorithm 1's clustered sweep
+          repro.plan.compact     finished levels leave the sweep  stats kept, of
         repro.plan.lockstep      offset water-filling sweep (replans)
         repro.plan.shared        shared-horizon sweep (replans)
+          repro.plan.compact
         repro.plan.replay        the winner's batch list
       repro.validate             BatchPlan.validate
       repro.simulate             the analytic timeline
@@ -41,6 +43,7 @@ PROVISION = "repro.provision"
 ALLOCATE = "repro.allocate"
 PLAN = "repro.plan"
 PLAN_CLUSTERED = "repro.plan.clustered"
+PLAN_COMPACT = "repro.plan.compact"
 PLAN_LOCKSTEP = "repro.plan.lockstep"
 PLAN_SHARED = "repro.plan.shared"
 PLAN_REPLAY = "repro.plan.replay"

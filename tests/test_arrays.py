@@ -193,6 +193,49 @@ class TestPassEquivalence:
             assert plan.makespan() == float(ms2[i])
 
 
+#: (K, deadline range in s, largest offset, delay) of a sweep whose
+#: rows finish rounds apart, so its working set is compacted
+COMPACT_CASES = {
+    # the chip's g at K = 128, deadlines spread like a dense round
+    "dense-k128": (128, (0.160, 0.550), 0,
+                   DelayModel(a=1.8e-4, b=8.84e-4)),
+    "uniform-k32": (32, (0.300, 0.300), 0,
+                    DelayModel(a=1.8e-4, b=8.84e-4)),
+    "offsets-k24": (24, (3.0, 9.0), 8, DELAY),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COMPACT_CASES))
+def test_compacted_sweep_matches_scalar(case):
+    """Levels that leave the sweep's working set early, late or never
+    keep the scalar pass's counts, makespan, batches and start times."""
+    K, (lo, hi), off_max, delay = COMPACT_CASES[case]
+    rng = np.random.default_rng(14)
+    ids = list(range(K))
+    tp = {k: float(d) for k, d in zip(ids, rng.uniform(lo, hi, K))}
+    off = {k: int(o) for k, o in zip(ids, rng.integers(0, off_max + 1, K))}
+    arr = ServiceArrays.build(ids, tp, off)
+    level_max = max(off[k] + delay.max_steps(tp[k]) for k in ids)
+    levels = np.arange(1, level_max + 1, dtype=np.int64)
+    hist: list = []
+    Tc, ms, _, _ = arrays._clustered_rounds(
+        arr.ids, arr.tau_prime, arr.offsets, delay, levels, history=hist)
+
+    first_rows = next(rows for rows, *_ in hist if rows.size < levels.size)
+    dropped = np.setdiff1d(levels - 1, first_rows)
+    picks = {0, levels.size - 1, first_best(Tc, QUALITY)[0],
+             int(hist[-1][0][-1]), int(dropped[-1])}
+    for i in sorted(picks):
+        ref = stacking_pass(ids, tp, delay, int(levels[i]), offsets=off)
+        assert Tc[i].tolist() == [ref.steps_completed[k] for k in ids]
+        assert float(ms[i]) == ref.makespan()
+        batches, starts = arrays._replay_clustered(arr.ids, i, hist, delay)
+        assert batches == ref.batches and starts == ref.start_times
+        assert_plans_equal(ref, stacking_pass_vec(ids, tp, delay,
+                                                  int(levels[i]),
+                                                  offsets=off))
+
+
 class TestSearchEquivalence:
     def test_stacking_full_search(self):
         for seed in range(10):

@@ -15,7 +15,9 @@ import pytest
 from repro import spans
 from repro.api import DiffusionWorkload, Provisioner
 from repro.configs.ddim_cifar10 import SMOKE
+from repro.core import arrays
 from repro.core.delay_model import DelayModel
+from repro.core.quality_model import PowerLawFID
 from repro.core.service import make_scenario
 
 TRUE = DelayModel(a=0.1, b=0.2)
@@ -31,6 +33,7 @@ PARENTS = {
     spans.ALLOCATE: {spans.PROVISION},
     spans.PLAN: {spans.PROVISION},
     spans.PLAN_CLUSTERED: _PLAN_PARENTS,
+    spans.PLAN_COMPACT: {spans.PLAN_CLUSTERED, spans.PLAN_SHARED},
     spans.PLAN_LOCKSTEP: _PLAN_PARENTS,
     spans.PLAN_SHARED: _PLAN_PARENTS,
     spans.PLAN_REPLAY: _PLAN_PARENTS,
@@ -177,3 +180,21 @@ def test_run_id_shared_within_a_round_and_new_per_round(traced):
         inner = [s for s in got if _inside(s, top)]
         assert len(inner) > 1
         assert {s["stats"]["run"] for s in inner} == {run}
+
+
+def test_dense_sweep_compacts_and_a_single_level_does_not(tmp_path):
+    """A K = 128 STACKING search drops finished levels from its sweep
+    (``repro.plan.compact`` under ``repro.plan.clustered``, fewer rows
+    kept than there were); a single-level pass never compacts."""
+    g = DelayModel(a=1.8e-4, b=8.84e-4)
+    scn = make_scenario(K=128, tau_min=0.16, tau_max=0.55, seed=14)
+    tp = {s.id: s.deadline for s in scn.services}
+    with jax.profiler.trace(str(tmp_path)):
+        arrays.stacking_pass_vec(list(tp), tp, g, t_star=40)
+        arrays.stacking_vec(scn.services, tp, g, PowerLawFID())
+    got = _read(str(tmp_path))
+    compact = [s for s in got if s["name"] == spans.PLAN_COMPACT]
+    assert compact
+    for s in compact:
+        assert s["parent"]["name"] == spans.PLAN_CLUSTERED
+        assert 0 < s["stats"]["kept"] < s["stats"]["of"]
