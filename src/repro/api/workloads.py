@@ -26,7 +26,8 @@ from repro.core.quality_model import PowerLawFID, QualityModel
 
 @register_workload("diffusion")
 class DiffusionWorkload:
-    """Batch denoising on the DDIM U-Net (the paper's workload)."""
+    """Batch denoising on a DDIM denoiser (the paper's workload): the
+    U-Net or a DiT, as ``cfg.arch`` says (default: the smoke U-Net)."""
 
     name = "diffusion"
 
@@ -45,13 +46,13 @@ class DiffusionWorkload:
         if self._executor is None:
             import jax
             from repro.configs.ddim_cifar10 import SMOKE
-            from repro.diffusion import unet
-            from repro.diffusion.executor import BatchDenoisingExecutor
+            from repro.diffusion.executor import BatchDenoisingExecutor, \
+                denoiser
             from repro.models.params import init_params
             cfg = self.cfg if self.cfg is not None else SMOKE
             params = self.params
             if params is None:
-                params = init_params(unet.schema(cfg),
+                params = init_params(denoiser(cfg).schema(cfg),
                                      jax.random.PRNGKey(self.init_seed))
             self.cfg, self.params = cfg, params
             self._executor = BatchDenoisingExecutor(

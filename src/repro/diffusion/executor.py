@@ -1,4 +1,8 @@
-"""Batch-denoising executor: runs a BatchPlan against a real DDIM U-Net.
+"""Batch-denoising executor: runs a BatchPlan against a real denoiser.
+
+The denoiser is the configuration's ``arch`` (``denoiser``): the DDIM
+U-Net (``"unet"``) or a DiT (``"dit"``); everything below its ``eps_fn``
+(sessions, pool, DDIM update) is the same for both.
 
 Each service k ends the plan with T_k steps; its DDIM schedule is the
 evenly-spaced T_k-step subsequence.  Batch n gathers the current latents
@@ -47,7 +51,18 @@ from repro import spans
 from repro.configs.ddim_cifar10 import UNetConfig
 from repro.core.execution import EXEC_ENGINES, exec_engine_default
 from repro.core.plan import BatchPlan
-from repro.diffusion import ddim, unet
+from repro.diffusion import ddim, dit, unet
+
+DENOISERS = {"unet": unet, "dit": dit}
+
+
+def denoiser(cfg: UNetConfig):
+    """The module (``schema``, ``forward``) of ``cfg.arch``."""
+    try:
+        return DENOISERS[cfg.arch]
+    except KeyError:
+        raise ValueError(f"unknown denoiser arch {cfg.arch!r}; expected "
+                         f"one of {sorted(DENOISERS)}") from None
 
 
 class BatchDenoisingExecutor:
@@ -56,6 +71,7 @@ class BatchDenoisingExecutor:
                  exec_engine: Optional[str] = None):
         self.cfg = cfg
         self.params = params
+        self._forward = denoiser(cfg).forward
         self.T_train = num_train_timesteps or cfg.num_train_timesteps
         if exec_engine is not None and exec_engine not in EXEC_ENGINES:
             raise ValueError(f"unknown exec_engine {exec_engine!r}; "
@@ -79,7 +95,7 @@ class BatchDenoisingExecutor:
         self.dispatches = 0
 
     def eps_fn(self, params, x, t):
-        return unet.forward(self.cfg, params, x, t)
+        return self._forward(self.cfg, params, x, t)
 
     def step_fn(self, params, x, t_now, t_next):
         """One batched DDIM step with per-sample timesteps — the
@@ -104,7 +120,7 @@ class BatchDenoisingExecutor:
         prog = self._programs.get(key)
         if prog is None:
             t0 = time.perf_counter()
-            with spans.span(spans.COMPILE, kind=key[0]):
+            with spans.span(spans.COMPILE, kind=key[0], arch=self.cfg.arch):
                 prog = jax.jit(build, donate_argnums=donate) \
                     .lower(*example_args).compile()
             self.compile_log.append((key, time.perf_counter() - t0))
@@ -306,6 +322,7 @@ class DenoiseSession:
         mine = self.executor.compile_log[self._clog0:]
         return {
             "exec_engine": "dict",
+            "arch": self.executor.cfg.arch,
             "dispatches": int(sum(self._dispatch.values())),
             "by_size": {str(b): int(n)
                         for b, n in sorted(self._dispatch.items())},

@@ -158,9 +158,10 @@ def test_replan_spans_count_replans(traced, which):
 
 def test_one_compile_span_per_new_compile_log_entry(traced):
     got, rounds, _, compiled = traced
-    kinds = [s["stats"]["kind"] for s in _under(got, rounds[1],
-                                                spans.COMPILE)]
+    compiles = _under(got, rounds[1], spans.COMPILE)
+    kinds = [s["stats"]["kind"] for s in compiles]
     assert compiled and kinds == [key[0] for key, _ in compiled]
+    assert {s["stats"]["arch"] for s in compiles} == {"unet"}
     assert not _under(got, rounds[0], spans.COMPILE)
 
 

@@ -6,7 +6,9 @@ Mosaic cannot lower, kernels over their scoped VMEM, programs over the
 device's memory), though nothing runs.  Covered here: the Pallas
 GroupNorm+SiLU kernel at every width the published ``ddim-cifar10``
 U-Net feeds it, and the bucketed gather->DDIM-step->scatter program at
-that width, compiled from ``jax.eval_shape`` parameter shapes.
+that width, compiled from ``jax.eval_shape`` parameter shapes; and the
+same program for DiT-XL/2 at 256x256, with the flash-attention kernel
+at its published shapes.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and every
@@ -22,12 +24,12 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.ddim_cifar10 import CONFIG
-from repro.diffusion import unet
+from repro.configs.ddim_cifar10 import CONFIG, DIT_XL_2
+from repro.diffusion import dit, unet
 from repro.diffusion.bucketed import pool_step
 from repro.diffusion.executor import BatchDenoisingExecutor
 from repro.kernels.groupnorm_silu.kernel import groupnorm_silu_pallas
-from repro.models.params import init_params
+from repro.models.params import abstract_params, init_params
 
 # (H, W, C) inputs of every GroupNorm+SiLU in the CONFIG U-Net, plus
 # (32, 32, 512): the largest single-image block (2 MB f32) the kernel
@@ -36,6 +38,7 @@ GN_WIDTHS = [(4, 4, 256), (4, 4, 512), (8, 8, 256), (8, 8, 512),
              (16, 16, 128), (16, 16, 256), (16, 16, 384), (16, 16, 512),
              (32, 32, 128), (32, 32, 256), (32, 32, 384), (32, 32, 512)]
 BUCKET = 8          # largest batch bucket of an 8-service session
+DIT_BUCKET = 32     # largest bucket of the 32-service DiT cell
 
 
 @pytest.fixture(scope="module")
@@ -120,5 +123,33 @@ def test_bucketed_step_compiles_for_v5e(one_chip, monkeypatch):
                       (params, pool, lane, lane, lane), donate=(1,))
     # one kernel per GroupNorm+SiLU of the forward pass
     assert prog.as_text().count("tpu_custom_call") == 45
+    mem = prog.memory_analysis()
+    assert mem.argument_size_in_bytes > 4 * n_params
+
+
+def test_dit_bucketed_step_compiles_for_v5e(one_chip, monkeypatch):
+    """DiT-XL/2's bucketed step at its largest bucket: one
+    flash-attention kernel per block, on q, k and v of [32 rows, 16
+    heads, 256 tokens, 72], and the f32 weights as arguments."""
+    import repro.kernels
+    monkeypatch.setattr(repro.kernels, "use_pallas", lambda: "tpu")
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        abstract_params(dit.schema(DIT_XL_2), jnp.float32))
+    n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
+    assert 668e6 < n_params < 682e6
+    ex = BatchDenoisingExecutor(DIT_XL_2, params, exec_engine="bucketed")
+    rows = DIT_BUCKET + 1
+    shape = (DIT_XL_2.image_size, DIT_XL_2.image_size, DIT_XL_2.in_channels)
+    pool = jax.ShapeDtypeStruct((rows,) + shape, jnp.float32,
+                                sharding=one_chip)
+    lane = jax.ShapeDtypeStruct((DIT_BUCKET,), jnp.int32, sharding=one_chip)
+    prog = ex.program(("bstep", rows, DIT_BUCKET), pool_step(ex.step_fn),
+                      (params, pool, lane, lane, lane), donate=(1,))
+    calls = [line for line in prog.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == DIT_XL_2.depth
+    for call in calls:
+        assert call.split(" = ")[1].startswith("f32[32,16,256,72]")
     mem = prog.memory_analysis()
     assert mem.argument_size_in_bytes > 4 * n_params
