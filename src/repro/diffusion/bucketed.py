@@ -212,7 +212,7 @@ class BucketedDenoiseSession(DenoiseSession):
                 compile_by_bucket[b] = compile_by_bucket.get(b, 0.0) + s
         return {
             "exec_engine": "bucketed",
-            "arch": self.executor.cfg.arch,
+            **self.executor.arch_stats(),
             "dispatches": int(sum(self._dispatch.values())
                               + sum(self._scan_dispatch.values())),
             "by_bucket": {str(b): int(n)

@@ -22,8 +22,11 @@ rolled loop made XLA hoist a bfloat16 copy of every block's weights out
 of the loop, 1.35 GB written and read again each step, and took 13.1 /
 120.3 ms a step at bucket 2 / 32 against 8.2 / 112.0 ms unrolled, for
 2-3.5 s to compile a bucket program against 14-22 s.  Attention is
-``models.layers.chunked_attention`` without a mask: on a TPU the repo's
-Pallas flash-attention kernel.
+``kernels.flash_attention.ops.mha_whole`` on q, k and v as the one qkv
+projection leaves them, ``(B, 1, N, D)`` with the heads side by side on
+the lanes: on a TPU one whole-sequence Pallas call a block, fed with no
+relayout copy.  A row has to fit VMEM (256 tokens of 1152 do);
+``attention_path`` names the path and refuses a longer sequence.
 """
 
 from __future__ import annotations
@@ -36,7 +39,7 @@ import numpy as np
 
 from repro.configs.ddim_cifar10 import UNetConfig
 from repro.diffusion.unet import timestep_embedding
-from repro.models.layers import chunked_attention
+from repro.kernels.flash_attention.ops import mha_whole, mha_whole_path
 from repro.models.params import P
 
 LN_EPS = 1e-6
@@ -97,6 +100,14 @@ def pos_embed(hidden: int, grid: int) -> np.ndarray:
                           axis=1).astype(np.float32)
 
 
+def attention_path(cfg: UNetConfig) -> str:
+    """The attention every forward of ``cfg`` runs, ``"whole"`` or
+    ``"xla"`` (``ops.mha_whole_path``, which ``mha_whole`` itself
+    follows)."""
+    tokens = (cfg.image_size // cfg.patch_size) ** 2
+    return mha_whole_path(tokens, cfg.hidden_size)
+
+
 def _linear(p, x):
     return x @ p["w"] + p["b"]
 
@@ -113,15 +124,14 @@ def _modulate(x, shift, scale):
 
 def _block(cfg: UNetConfig, x, silu_c, p):
     B, N, D = x.shape
-    H = cfg.num_heads
     with jax.named_scope("dit.block"):
         (s_msa, sc_msa, g_msa,
          s_mlp, sc_mlp, g_mlp) = jnp.split(_linear(p["ada"], silu_c), 6, -1)
         with jax.named_scope("dit.attn"):
             h = _modulate(_layer_norm(x), s_msa, sc_msa)
             q, k, v = jnp.split(_linear(p["qkv"], h), 3, -1)
-            q, k, v = (a.reshape(B, N, H, D // H) for a in (q, k, v))
-            o = chunked_attention(q, k, v, causal=False).reshape(B, N, D)
+            q, k, v = (a.reshape(B, 1, N, D) for a in (q, k, v))
+            o = mha_whole(q, k, v, num_heads=cfg.num_heads).reshape(B, N, D)
             x = x + g_msa[:, None] * _linear(p["proj"], o)
         with jax.named_scope("dit.mlp"):
             h = _modulate(_layer_norm(x), s_mlp, sc_mlp)

@@ -120,12 +120,21 @@ class BatchDenoisingExecutor:
         prog = self._programs.get(key)
         if prog is None:
             t0 = time.perf_counter()
-            with spans.span(spans.COMPILE, kind=key[0], arch=self.cfg.arch):
+            with spans.span(spans.COMPILE, kind=key[0],
+                            **self.arch_stats()):
                 prog = jax.jit(build, donate_argnums=donate) \
                     .lower(*example_args).compile()
             self.compile_log.append((key, time.perf_counter() - t0))
             self._programs[key] = prog
         return prog
+
+    def arch_stats(self) -> dict:
+        """``arch``, and for a DiT the ``attn`` path its forward runs
+        (``dit.attention_path``)."""
+        out = {"arch": self.cfg.arch}
+        if self.cfg.arch == "dit":
+            out["attn"] = dit.attention_path(self.cfg)
+        return out
 
     def open_session(self, plan: BatchPlan, key,
                      exec_engine: Optional[str] = None
@@ -322,7 +331,7 @@ class DenoiseSession:
         mine = self.executor.compile_log[self._clog0:]
         return {
             "exec_engine": "dict",
-            "arch": self.executor.cfg.arch,
+            **self.executor.arch_stats(),
             "dispatches": int(sum(self._dispatch.values())),
             "by_size": {str(b): int(n)
                         for b, n in sorted(self._dispatch.items())},

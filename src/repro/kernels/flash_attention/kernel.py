@@ -12,6 +12,11 @@ Tiling (DESIGN.md §3: HBM->VMEM->MXU):
   Causal masking skips *entire* kv blocks past the diagonal with
   @pl.when (no wasted MXU work — this is the "causal chunk skip" the
   pure-XLA chunked_attention path lacks; see EXPERIMENTS.md §Perf).
+
+``mha_whole_pallas`` is a separate kernel for short, non-causal
+sequences (the DiT's 256 tokens): one grid step a row, q, k and v whole
+in VMEM as [S, H*Dh] with the heads side by side on the lanes, so the
+caller feeds it the qkv projection's output with no relayout.
 """
 
 from __future__ import annotations
@@ -125,3 +130,46 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         interpret=interpret,
     )(qt, kt, vt)
     return jnp.swapaxes(out, 1, 2)
+
+
+def _mha_whole_kernel(q_ref, k_ref, v_ref, o_ref, *, num_heads: int,
+                      head_dim: int, scale: float):
+    for h in range(num_heads):
+        lanes = pl.ds(h * head_dim, head_dim)
+        q = q_ref[0, 0, :, lanes].astype(jnp.float32)     # (S, Dh)
+        k = k_ref[0, 0, :, lanes].astype(jnp.float32)
+        v = v_ref[0, 0, :, lanes].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (S, S)
+        p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+        o = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        o_ref[0, 0, :, lanes] = (o / p.sum(axis=-1, keepdims=True)
+                                 ).astype(o_ref.dtype)
+
+
+def mha_whole_pallas(q, k, v, *, num_heads: int, interpret: bool = False):
+    """Non-causal multi-head attention over whole, short sequences.
+
+    q, k, v: (B, 1, S, H*Dh), heads side by side on the lanes, as a
+    fused qkv projection leaves them -> (B, 1, S, H*Dh).  The grid runs
+    over rows; each step holds one row's q, k and v whole in VMEM and
+    takes the heads one after another as static lane slices, with one
+    softmax over all S keys: no tiling and no online rescale.  The
+    dispatcher, ``ops.mha_whole``, checks that a row fits."""
+    B, one, S, W = q.shape
+    assert one == 1 and k.shape == v.shape == q.shape
+    assert W % num_heads == 0
+    head_dim = W // num_heads
+    block = pl.BlockSpec((1, 1, S, W), lambda b: (b, 0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_mha_whole_kernel, num_heads=num_heads,
+                          head_dim=head_dim, scale=1.0 / (head_dim ** 0.5)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(B,),
+        in_specs=[block, block, block],
+        out_specs=block,
+        interpret=interpret,
+    )(q, k, v)

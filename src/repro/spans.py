@@ -27,7 +27,8 @@ round, per stage or per batch, never per service.  They nest so:
           repro.plan.*
         repro.finish             the session's images to the host
     repro.compile                a step program compiled on first use
-                                 (under whatever asked for it)  stat kind
+                                 (under whatever asked for it)  stats kind,
+                                 arch, and attn for a DiT
 
 Every span opened inside a ``repro.provision`` carries that round's
 ``run`` stat, one process-wide id per round.

@@ -23,12 +23,14 @@ if str(ROOT) not in sys.path:
 
 from chipbench import reference, spec  # noqa: E402
 from repro.api import DiffusionWorkload, Provisioner  # noqa: E402
+from repro.core.plan import BatchPlan  # noqa: E402
 from repro.configs.ddim_cifar10 import DIT_XL_2, UNetConfig  # noqa: E402
 from repro.core.delay_model import DelayModel  # noqa: E402
 from repro.core.quality_model import PowerLawFID  # noqa: E402
 from repro.core.service import make_scenario  # noqa: E402
 from repro.core.stacking import stacking  # noqa: E402
 from repro.diffusion import dit  # noqa: E402
+from repro.diffusion.executor import BatchDenoisingExecutor  # noqa: E402
 from repro.models.params import abstract_params  # noqa: E402
 
 REF = spec.reference("dit-xl-2-256")
@@ -41,8 +43,8 @@ SEED = 2_147_483_659          # above 2**31, as the benchmark's seeds are
 
 # Both sides compute in float32 on the CPU, where a float32 matmul is
 # exact to float32 rounding; they differ only in the order of sums (a
-# reshape-and-matmul patch embedding against a strided convolution, the
-# online-softmax attention against one softmax over all scores, a scan
+# reshape-and-matmul patch embedding against a strided convolution,
+# attention on the heads side by side against one head at a time, a scan
 # against a loop), so a relative error of a few 1e-7 is expected.
 # 1e-5 leaves room for that and none for a missing or misplaced term.
 FWD_TOL = 1e-5
@@ -62,8 +64,8 @@ def _rel(a, b):
 
 @pytest.mark.parametrize("kernel", ["xla", "pallas-interpret"])
 def test_forward_matches_reference(params, kernel, monkeypatch):
-    """Attention by XLA's online softmax, and by the flash kernel that
-    a TPU runs (here interpreted)."""
+    """Attention by XLA's softmax, and by the whole-sequence kernel
+    that a TPU runs (here interpreted)."""
     if kernel == "pallas-interpret":
         monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
     x = jax.random.normal(jax.random.PRNGKey(1), (3, 8, 8, 4))
@@ -199,3 +201,47 @@ def test_harness_builds_the_dit_config_from_its_file():
     assert sizes["learn_sigma"] is True
     assert sizes["class_label"] == sizes["num_classes"]
     assert cfg == DIT_XL_2
+
+
+def test_attention_path_engages_the_whole_sequence_kernel(monkeypatch):
+    """A session of 32 DiT-XL/2 services on a TPU runs the whole-sequence
+    kernel; 1024 tokens (DiT-XL/2 at 512x512) do not fit a row in VMEM
+    and are refused, not run another way; off the chip XLA runs
+    attention; a U-Net session names no attention path."""
+    import repro.kernels
+    monkeypatch.setattr(repro.kernels, "use_pallas", lambda: "tpu")
+    params = abstract_params(dit.schema(DIT_XL_2), jnp.float32)
+    ex = BatchDenoisingExecutor(DIT_XL_2, params, exec_engine="bucketed")
+    plan = BatchPlan(batches=[], start_times=[],
+                     steps_completed={k: 20 for k in range(32)},
+                     delay=DelayModel(a=0.0034, b=0.0007))
+    tel = ex.open_session(plan, jax.random.PRNGKey(0)).telemetry()
+    assert tel["arch"] == "dit" and tel["attn"] == "whole"
+    assert ex.arch_stats() == {"arch": "dit", "attn": "whole"}
+    dit_512 = dataclasses.replace(DIT_XL_2, image_size=64)
+    with pytest.raises(ValueError, match="does not fit"):
+        dit.attention_path(dit_512)
+    monkeypatch.setattr(repro.kernels, "use_pallas", lambda: "ref")
+    assert dit.attention_path(DIT_XL_2) == "xla"
+    unet_cfg = dataclasses.replace(SMALL, arch="unet")
+    assert "attn" not in BatchDenoisingExecutor(unet_cfg, {}).arch_stats()
+
+
+def test_roofline_reads_the_whole_sequence_call():
+    """``flash_attn_roofline`` finds the whole-sequence kernel's call,
+    q, k, v and o each [32, 1, 256, 1152], by its signature as the
+    device trace prints it, and counts it as the tiled kernel's
+    [32, 16, 256, 72]: with h = 1 and d = H*Dh the operations and the
+    bytes are unchanged."""
+    mod = spec.metric_module("flash_attn_roofline")
+    x = "f32[32,1,256,1152]{3,2,1,0:T(8,128)}"
+    call = (f"%dit.attn.56 = {x} custom-call({x} %get-tuple-element.223, "
+            f"{x} %get-tuple-element.222, {x} %get-tuple-element.221), "
+            'custom_call_target="tpu_custom_call", operand_layout_'
+            "constraints={f32[32,1,256,1152]{3,2,1,0}, "
+            "f32[32,1,256,1152]{3,2,1,0}, f32[32,1,256,1152]{3,2,1,0}}")
+    assert list(mod.calls([(call, 1e-4)])) == [(32, 1, 256, 1152, 1e-4)]
+    B, H, S, Dh = 32, 16, 256, 72
+    assert mod.call_flops(32, 1, 256, 1152) == 4 * B * H * S * S * Dh
+    assert mod.call_bytes(32, 1, 256, 1152) == 4 * B * S * H * Dh * 4
+    assert mod.call_flops(32, 1, 256, 1152) == mod.call_flops(B, H, S, Dh)

@@ -8,7 +8,9 @@ import pytest
 
 from repro.kernels.decode_attention.kernel import decode_attention_pallas
 from repro.kernels.decode_attention.ref import decode_attention_ref
-from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.flash_attention.kernel import (flash_attention_pallas,
+                                                  mha_whole_pallas)
+from repro.kernels.flash_attention.ops import mha_whole_fits
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.groupnorm_silu.kernel import groupnorm_silu_pallas
 from repro.kernels.groupnorm_silu.ref import groupnorm_silu_ref
@@ -69,6 +71,34 @@ def test_flash_attention_window(window):
     want = attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("B,S,H,Dh", [
+    (2, 256, 16, 72),    # the DiT's own heads, 72 lanes wide
+    (3, 64, 2, 64),
+    (1, 128, 4, 32),
+])
+def test_mha_whole_matches_ref(B, S, H, Dh):
+    """The whole-sequence kernel on q, k, v laid out [B, 1, S, H*Dh],
+    against the oracle on the same values as [B, S, H, Dh]."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    q, k, v = (jax.random.normal(kk, (B, 1, S, H * Dh)) for kk in ks)
+    got = mha_whole_pallas(q, k, v, num_heads=H, interpret=True)
+    assert got.shape == q.shape
+    with jax.default_matmul_precision("highest"):
+        want = attention_ref(*(a.reshape(B, S, H, Dh) for a in (q, k, v)),
+                             causal=False)
+    np.testing.assert_allclose(np.asarray(got).reshape(B, S, H, Dh),
+                               np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+def test_mha_whole_fits_by_row_size():
+    """A row's q, k, v and output, double-buffered, plus one head's
+    scores: 9.7 MB at the DiT's 256 tokens of 1152 fits the 16 MiB
+    scoped VMEM, 38 MB at 1024 tokens (DiT-XL/2 at 512x512) does not."""
+    assert mha_whole_fits(256, 1152)
+    assert not mha_whole_fits(1024, 1152)
+    assert not mha_whole_fits(256, 2048)
 
 
 def test_chunked_attention_matches_ref_nondivisible_kv():

@@ -7,8 +7,8 @@ device's memory), though nothing runs.  Covered here: the Pallas
 GroupNorm+SiLU kernel at every width the published ``ddim-cifar10``
 U-Net feeds it, and the bucketed gather->DDIM-step->scatter program at
 that width, compiled from ``jax.eval_shape`` parameter shapes; and the
-same program for DiT-XL/2 at 256x256, with the flash-attention kernel
-at its published shapes.
+same program for DiT-XL/2 at 256x256, with the whole-sequence attention
+kernel at its published shapes.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and every
@@ -17,6 +17,7 @@ file so that one worker loads the library for all of them.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -129,8 +130,10 @@ def test_bucketed_step_compiles_for_v5e(one_chip, monkeypatch):
 
 def test_dit_bucketed_step_compiles_for_v5e(one_chip, monkeypatch):
     """DiT-XL/2's bucketed step at its largest bucket: one
-    flash-attention kernel per block, on q, k and v of [32 rows, 16
-    heads, 256 tokens, 72], and the f32 weights as arguments."""
+    whole-sequence attention kernel per block, on q, k and v of [32 rows,
+    1, 256 tokens, 16 heads x 72] straight from the qkv projection, with
+    no relayout copy to or from heads-first [32, 256, 16, 72], and the
+    f32 weights as arguments."""
     import repro.kernels
     monkeypatch.setattr(repro.kernels, "use_pallas", lambda: "tpu")
     params = jax.tree.map(
@@ -146,10 +149,26 @@ def test_dit_bucketed_step_compiles_for_v5e(one_chip, monkeypatch):
     lane = jax.ShapeDtypeStruct((DIT_BUCKET,), jnp.int32, sharding=one_chip)
     prog = ex.program(("bstep", rows, DIT_BUCKET), pool_step(ex.step_fn),
                       (params, pool, lane, lane, lane), donate=(1,))
-    calls = [line for line in prog.as_text().splitlines()
-             if "tpu_custom_call" in line]
+    lines = prog.as_text().splitlines()
+    calls = [line for line in lines if "tpu_custom_call" in line]
     assert len(calls) == DIT_XL_2.depth
     for call in calls:
-        assert call.split(" = ")[1].startswith("f32[32,16,256,72]")
+        assert call.split(" = ")[1].startswith("f32[32,1,256,1152]")
+    # no copy lays q, k, v or o out heads-first, nor feeds a call, even
+    # through a bitcast
+    assert not [line for line in lines if " copy(" in line
+                and re.search(r"= \w+\[32,256,16,72\]", line)]
+    defs = {name: (kind, re.findall(r"%([\w.-]+)", args))
+            for name, kind, args in re.findall(
+                r"%([\w.-]+) = \S+ ([\w-]+)\(([^)]*)\)", "\n".join(lines))}
+
+    def made_by(name):
+        while defs[name][0] == "bitcast":
+            name = defs[name][1][0]
+        return defs[name][0]
+
+    for call in calls:
+        for arg in defs[call.split(" = ")[0].strip().lstrip("%")][1]:
+            assert not made_by(arg).startswith("copy"), call
     mem = prog.memory_analysis()
     assert mem.argument_size_in_bytes > 4 * n_params
