@@ -1,7 +1,7 @@
 """Sim-to-real suite: STACKING plans executed on the real denoiser
 with online delay refit (``repro.core.execution.ExecutionLoop``).
 
-Three claims, each pinned by a flag in ``benchmarks/baseline.json``:
+Four claims, each pinned by a flag in ``benchmarks/baseline.json``:
 
   * ``e2e_closed_loop_beats_open_loop`` — under an injected 2x delay
     misestimate (the planner believes the hardware is twice as fast as
@@ -17,22 +17,11 @@ Three claims, each pinned by a flag in ``benchmarks/baseline.json``:
   * ``e2e_decode_closed_ok`` — the same loop drives a ServingEngine
     decode session (llm_decode executor) to completion: every admitted
     request ends with exactly its per-service step count of tokens.
-  * ``exec_bucketed_images_match`` — the device-resident bucketed
-    engine reproduces the dict engine's final images within the
-    documented ``MATCH_TOL`` on a mixed-batch-size STACKING plan (the
-    SMOKE U-Net).
-  * ``exec_bucketed_speedup`` — steady-state wall-clock for a full
-    STACKING plan with >=4 distinct batch sizes, bucketed >= 1.5x
-    faster than dict, compile excluded (per-bucket compile columns are
-    separate rows).  Measured on a micro U-Net whose per-step compute
-    is commensurate with the per-step dispatch machinery the engine
-    exists to remove: on this 1-core CPU runner the SMOKE U-Net's
-    batch-linear compute buries machinery wins (and power-of-two
-    padding costs real extra FLOPs), so the SMOKE-model ratio is
-    recorded as an ungated trend row (``exec_bucketed_smoke_ratio``)
-    and the gate pins the machinery win where it is measurable — the
-    regime accelerators actually live in, where a denoising step is
-    dispatch-bound rather than FLOP-bound.
+  * ``exec_bucketed_images_match`` — the served (device-resident
+    pool) session reproduces the per-row reference session's final
+    images within the documented ``MATCH_TOL`` on a mixed-batch-size
+    STACKING plan (the SMOKE U-Net).  Speed is the chip benchmark's
+    (``chipbench/``), not this suite's.
 
 ``e2e_closed_over_open_ratio`` (closed delivered FID / open delivered
 FID, dimensionless so it transfers across runners) is additionally
@@ -129,8 +118,7 @@ def _diffusion_rows(rows, tag: str, K: int, multiples,
 def _exec_plan(K: int = 8, seed: int = 3):
     """A STACKING plan with >=4 distinct batch sizes (the composition
     shifts as services retire) and long stable phases (42 batches, 7
-    distinct sizes), shared by both engine comparisons — long enough
-    that a steady-state full-plan reading dwarfs timer noise."""
+    distinct sizes), so both padding and scan fusion are exercised."""
     from repro.core.bandwidth import inv_se_allocate, tau_prime_of
     from repro.core.delay_model import DelayModel
     from repro.core.quality_model import PowerLawFID
@@ -141,92 +129,29 @@ def _exec_plan(K: int = 8, seed: int = 3):
     return stacking(scn.services, tp, DelayModel(), PowerLawFID())
 
 
-def _steady_plan_s(ex, plan, key, engine: str, reps: int = 3) -> float:
-    """Best-of-``reps`` full-plan wall-clock, compile excluded: the
-    first run through each engine warms every program (AOT compiles
-    land in ``ex.compile_log``, never in an execution)."""
-    ex.run(plan, key, exec_engine=engine)          # warm all programs
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.time()
-        ex.run(plan, key, exec_engine=engine)
-        best = min(best, time.time() - t0)
-    return best
-
-
-def _engine_pair_s(cfg, plan, seed: int, reps: int = 3):
-    """(dict_s, bucketed_s, executor) steady-state plan timings on a
-    fresh executor for ``cfg``."""
-    import jax
-    from repro.diffusion import unet
-    from repro.diffusion.executor import BatchDenoisingExecutor
-    from repro.models.params import init_params
-    params = init_params(unet.schema(cfg), jax.random.PRNGKey(seed))
-    ex = BatchDenoisingExecutor(cfg, params)
-    key = jax.random.PRNGKey(seed + 1)
-    dict_s = _steady_plan_s(ex, plan, key, "dict", reps)
-    buck_s = _steady_plan_s(ex, plan, key, "bucketed", reps)
-    return dict_s, buck_s, ex
-
-
-def _exec_engine_rows(rows) -> None:
-    """Bucketed-vs-dict engine gates (see the module docstring)."""
+def _exec_match_rows(rows) -> None:
+    """Served session vs the per-row reference (module docstring)."""
     import jax
     import numpy as np
-    from repro.configs.ddim_cifar10 import SMOKE, UNetConfig
-    from repro.diffusion.bucketed import MATCH_TOL
+    from repro.configs.ddim_cifar10 import SMOKE
+    from repro.diffusion import unet
+    from repro.diffusion.executor import BatchDenoisingExecutor
+    from repro.diffusion.reference import MATCH_TOL, reference_images
+    from repro.models.params import init_params
 
     plan = _exec_plan()
     sizes = sorted({len(b) for b in plan.batches})
     assert len(sizes) >= 4, f"plan has batch sizes {sizes}"
-
-    # images-match gate: the real (SMOKE) U-Net, full plan, both engines
-    from repro.diffusion import unet
-    from repro.diffusion.executor import BatchDenoisingExecutor
-    from repro.models.params import init_params
     params = init_params(unet.schema(SMOKE), jax.random.PRNGKey(0))
     ex = BatchDenoisingExecutor(SMOKE, params)
     key = jax.random.PRNGKey(5)
-    imgs_d, _ = ex.run(plan, key, exec_engine="dict")
-    imgs_b, _ = ex.run(plan, key, exec_engine="bucketed")
-    maxdiff = max(float(np.abs(imgs_b[k] - imgs_d[k]).max())
-                  for k in imgs_d)
-    ok = all(np.allclose(imgs_b[k], imgs_d[k], **MATCH_TOL)
-             for k in imgs_d)
+    want = reference_images(ex, plan, key)
+    got, _ = ex.run(plan, key)
+    maxdiff = max(float(np.abs(got[k] - want[k]).max()) for k in want)
+    ok = all(np.allclose(got[k], want[k], **MATCH_TOL) for k in want)
     rows.append(("exec_bucketed_images_match", float(ok),
-                 f"1=bucketed==dict within atol={MATCH_TOL['atol']:g} "
+                 f"1=served==reference within atol={MATCH_TOL['atol']:g} "
                  f"(maxdiff={maxdiff:.2e}, sizes={sizes})"))
-
-    # smoke-model trend row (ungated: batch-linear compute dominates on
-    # a 1-core CPU runner, see module docstring)
-    smoke_d, smoke_b, _ = _engine_pair_s(SMOKE, plan, seed=0)
-    rows.append(("exec_bucketed_smoke_ratio", smoke_d / smoke_b,
-                 f"dict/bucketed steady plan wall on SMOKE "
-                 f"(dict={smoke_d*1e3:.1f}ms,buck={smoke_b*1e3:.1f}ms); "
-                 f"trend only"))
-
-    # machinery gate: micro U-Net — per-step compute commensurate with
-    # per-step dispatch machinery, the regime the engine targets
-    micro = UNetConfig(name="ddim-cifar10-micro", image_size=8,
-                       base_channels=8, channel_mults=(1,),
-                       num_res_blocks=1, attn_resolutions=(),
-                       num_groups=4)
-    micro_d, micro_b, mex = _engine_pair_s(micro, plan, seed=0, reps=5)
-    speedup = micro_d / micro_b
-    rows.append(("exec_bucketed_speedup", float(speedup >= 1.5),
-                 f"1=bucketed >=1.5x dict steady-state on micro U-Net "
-                 f"({speedup:.2f}x: dict={micro_d*1e3:.2f}ms,"
-                 f"buck={micro_b*1e3:.2f}ms, sizes={sizes})"))
-    rows.append(("exec_bucketed_micro_speedup_x", speedup,
-                 "raw machinery speedup behind the flag"))
-    # per-bucket compile columns: what the steady-state rows exclude
-    by_bucket = {}
-    for k, s in mex.compile_log:
-        if k[0] in ("bstep", "bscan"):
-            by_bucket[int(k[2])] = by_bucket.get(int(k[2]), 0.0) + s
-    for b, s in sorted(by_bucket.items()):
-        rows.append((f"exec_compile_bucket{b}_s", s,
-                     "bucketed AOT compile (excluded from speedup)"))
 
 
 def _decode_rows(rows) -> None:
@@ -256,7 +181,7 @@ def run(rows) -> None:
     kwargs = {"min_batches": 2, "drift_tol": 0.25, "headroom": 1.15}
     _diffusion_rows(rows, "smoke", K=5, multiples=(4, 6, 8, 10, 12),
                     execute_kwargs=kwargs)
-    _exec_engine_rows(rows)
+    _exec_match_rows(rows)
     _decode_rows(rows)
     if os.environ.get("E2E_FULL", "") not in ("", "0"):
         # nightly: a larger population on the same executor — more

@@ -1,7 +1,9 @@
 """Fig. 1a: per-step denoising delay vs. batch size, and the affine fit
-g(X) = aX + b, re-measured on this container's CPU with the smoke U-Net
-(the paper measures an RTX-3050; a, b are hardware constants by design —
-see DESIGN.md §3).  Also reports the analytic TPU v5e estimate."""
+g(X) = aX + b, re-measured on the host's CPU with the smoke U-Net (the
+paper measures an RTX-3050; a, b are hardware constants by design).
+Each size is read at what the served session pays for it, its padded
+power-of-two bucket, so sizes sharing a bucket (3-4, 5-8, 9-16) share a
+reading.  Also reports the analytic TPU v5e estimate."""
 
 import jax
 

@@ -58,7 +58,7 @@ def run(csv_rows):
     csv_rows.append(("kern_ssd_chunked", _time(f_ssd, xs, a, bm, cm, h0),
                      "S=512"))
 
-    # batched DDIM step at the bucketed engine's power-of-two batch
+    # batched DDIM step at the served session's power-of-two batch
     # buckets (SMOKE U-Net, gather->step->scatter pool program) — the
     # per-step cost Fig. 1a's delay model is fit over
     from repro.configs.ddim_cifar10 import SMOKE
@@ -68,7 +68,7 @@ def run(csv_rows):
     params = init_params(unet.schema(SMOKE), jax.random.PRNGKey(1))
     ex = BatchDenoisingExecutor(SMOKE, params)
     curve = ex.measure_delay_curve(ks[3], batch_sizes=(2, 4, 8),
-                                   reps=3, exec_engine="bucketed")
+                                   reps=3)
     compile_s = sum(s for _, s in ex.last_compile_log)
     for X, best in curve:
         csv_rows.append((f"kern_ddim_step_b{X}", best * 1e6,

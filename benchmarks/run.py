@@ -119,7 +119,6 @@ _DEVICE_COUNT = None
 def write_json(out_dir: Path, suite: str, rows, elapsed_s: float,
                sha: str, workers: int = 1) -> Path:
     from repro.core import arrays
-    from repro.core.execution import exec_engine_default
     out_dir.mkdir(parents=True, exist_ok=True)
     path = out_dir / f"BENCH_{suite}.json"
     payload = {
@@ -132,9 +131,6 @@ def write_json(out_dir: Path, suite: str, rows, elapsed_s: float,
         # the active planner engine (vec/scalar/jax, process default at
         # write time) so baseline refreshes can tell engine trends apart
         "engine": arrays.get_engine(),
-        # the active denoising execution engine (dict/bucketed process
-        # default), same reasoning for executor-side trends
-        "exec_engine": exec_engine_default(),
         # jax device count (0 = no jax), next to engine/workers
         "devices": device_count(),
         "rows": [{"name": n, "value": v, "derived": d}

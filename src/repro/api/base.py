@@ -18,9 +18,7 @@ Every facade — ``Provisioner``, ``OnlineProvisioner``,
                "closed" (ExecutionLoop with drift-triggered replanning)
 
 ``execute_kwargs`` passes loop tuning through to ``execute_plan``
-(``window``, ``drift_tol``, ...) plus ``exec_engine=`` to pick the
-denoising session engine (``"dict"`` reference / ``"bucketed"``
-device-resident — docs/PERFORMANCE.md).
+(``window``, ``drift_tol``, ...).
 
 ``provision(scenario, ...)`` is the single front door: it dispatches on
 scenario shape (fleet / multi-server / online / static) and reproduces

@@ -41,29 +41,14 @@ from repro.core.execution import (ExecutionLoop, ExecutionResult,
 from repro.core.plan import BatchPlan
 
 
-@register_executor("diffusion")
-def _diffusion_session(workload, plan, key, **kw):
-    return workload.open_session(plan, key, **kw)
-
-
-@register_executor("llm_decode")
-def _llm_decode_session(workload, plan, key,
-                        exec_engine: Optional[str] = None, **kw):
-    if exec_engine not in (None, "dict"):
-        raise ValueError(f"llm_decode executor has no "
-                         f"exec_engine={exec_engine!r} (the bucketed "
-                         f"engine is diffusion-only)")
+@register_executor("diffusion", aliases=("llm_decode",))
+def _workload_session(workload, plan, key, **kw):
     return workload.open_session(plan, key, **kw)
 
 
 @register_executor("simulated")
 def _simulated_session(workload, plan, key, *, true_delay: DelayModel,
-                       noise: float = 0.0, seed: int = 0,
-                       exec_engine: Optional[str] = None):
-    if exec_engine not in (None, "dict"):
-        raise ValueError(f"simulated executor has no "
-                         f"exec_engine={exec_engine!r} (the bucketed "
-                         f"engine is diffusion-only)")
+                       noise: float = 0.0, seed: int = 0):
     return SimulatedSession(plan, true_delay, noise=noise, seed=seed)
 
 
@@ -90,19 +75,12 @@ def execute_plan(scenario, plan: BatchPlan, alloc, workload=None, *,
                  executor_kwargs: Optional[dict] = None,
                  window: int = 32, drift_tol: float = 0.25,
                  min_batches: int = 3, max_replans: int = 8,
-                 headroom: float = 1.0,
-                 exec_engine: Optional[str] = None) -> ExecutionResult:
+                 headroom: float = 1.0) -> ExecutionResult:
     """Execute a planned batch schedule on a real (or simulated)
     executor.  ``mode="open"`` runs the plan as given (telemetry +
     rolling refit only); ``mode="closed"`` replans mid-flight through
     the offset-aware path when measured delay drifts (``drift_tol``,
-    ``min_batches``, ``max_replans``, ``headroom`` tune the loop).
-    ``exec_engine`` picks the denoising session engine (``"dict"`` /
-    ``"bucketed"``; ``None`` = the executor's default) and is recorded
-    in the result telemetry."""
-    if exec_engine is not None:
-        executor_kwargs = dict(executor_kwargs or {})
-        executor_kwargs.setdefault("exec_engine", exec_engine)
+    ``min_batches``, ``max_replans``, ``headroom`` tune the loop)."""
     with spans.span(spans.EXECUTE):
         session = make_session(workload, plan, key, executor=executor,
                                executor_kwargs=executor_kwargs)
@@ -112,8 +90,7 @@ def execute_plan(scenario, plan: BatchPlan, alloc, workload=None, *,
             allocator=ALLOCATORS.resolve(allocator),
             mode=mode, window=window, drift_tol=drift_tol,
             min_batches=min_batches, max_replans=max_replans,
-            headroom=headroom, validate=validate, engine=engine,
-            exec_engine=(executor_kwargs or {}).get("exec_engine"))
+            headroom=headroom, validate=validate, engine=engine)
         return loop.run()
 
 

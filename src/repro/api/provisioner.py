@@ -124,9 +124,7 @@ class Provisioner(BaseProvisioner):
     ``engine``/``devices``/``seed``/``execute`` are the unified facade
     kwargs (``repro.api.base``); ``execute_kwargs`` tunes the closed
     loop (``window``, ``drift_tol``, ``min_batches``, ``max_replans``,
-    ``headroom``, ``executor``, ``executor_kwargs``, plus
-    ``exec_engine="bucketed"`` to run the diffusion sessions on the
-    device-resident bucketed engine — docs/PERFORMANCE.md)."""
+    ``headroom``, ``executor``, ``executor_kwargs``)."""
 
     _LEGACY = ("workload", "scheduler", "allocator", "delay", "quality",
                "allocator_kwargs", "engine")

@@ -1,10 +1,11 @@
 """JAX's persistent compilation cache, placed by the entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``) call ``enable``
-before their first compile.  Importing ``repro`` never turns the cache
-on, so tests and compile rehearsals for a described chip stay silent
-(a program compiled for a chip that is not attached is written to the
-cache but cannot be read back).
+``benchmarks/run.py`` calls ``enable`` before its first compile;
+``python3 chipbench/run.py``, the chip's entry point, places the cache
+at the same ``<checkout>/.jax_cache`` itself.  Importing ``repro``
+never turns the cache on, so tests and compile rehearsals for a
+described chip stay silent (a program compiled for a chip that is not
+attached is written to the cache but cannot be read back).
 """
 
 from __future__ import annotations

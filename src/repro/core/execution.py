@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import os
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -50,23 +49,11 @@ from repro.core.simulator import ServiceOutcome
 
 _TIE = 1e-6   # deadline slack, matches repro.core.simulator
 
-#: Denoising execution engines (``repro.diffusion``): ``"dict"`` is the
-#: per-service-latent reference path, ``"bucketed"`` the device-resident
-#: padded-bucket engine (docs/PERFORMANCE.md, "The execution engine").
-EXEC_ENGINES = ("dict", "bucketed")
-
-
-def exec_engine_default() -> str:
-    """Process-default execution engine for the denoising executor —
-    the ``REPRO_EXEC_ENGINE`` environment variable, else ``"dict"``
-    (the bit-exact-per-row reference path)."""
-    return os.environ.get("REPRO_EXEC_ENGINE", "dict")
-
 
 def shape_bucket(n: int) -> int:
     """Power-of-two padded batch-size bucket (min 2).
 
-    This is the shape grid the bucketed denoising executor compiles
+    This is the shape grid the denoising executor compiles
     one gather->DDIM-step->scatter program per, and the grid
     ``ExecutionLoop`` telemetry groups measured per-batch wall-clock
     by (so drift is attributable to ``groupnorm_silu`` /
@@ -241,8 +228,7 @@ class ExecutionLoop:
                  window: int = 32, drift_tol: float = 0.25,
                  min_batches: int = 3, max_replans: int = 8,
                  headroom: float = 1.0, validate: bool = True,
-                 engine: Optional[str] = None,
-                 exec_engine: Optional[str] = None):
+                 engine: Optional[str] = None):
         if mode not in ("open", "closed"):
             raise ValueError(f"mode must be 'open' or 'closed', "
                              f"got {mode!r}")
@@ -262,10 +248,6 @@ class ExecutionLoop:
         self.headroom = float(headroom)
         self.validate = validate
         self.engine = engine
-        # the denoising-session engine the session was opened with —
-        # recorded for telemetry; the session itself (already built by
-        # the caller) is what actually implements it
-        self.exec_engine = exec_engine
 
         alloc = np.asarray(alloc, dtype=np.float64)
         self.alloc_map: Dict[int, float] = {
@@ -431,8 +413,7 @@ class ExecutionLoop:
             if outcomes else float("nan")
         tele_fn = getattr(self.session, "telemetry", None)
         session_tele = tele_fn() if callable(tele_fn) else None
-        exec_engine = self.exec_engine or \
-            (session_tele or {}).get("exec_engine", "")
+        exec_engine = (session_tele or {}).get("exec_engine", "")
         return ExecutionResult(
             outcomes=outcomes, records=self.records, content=content,
             delay=self.delay, mean_fid=mean_fid, outage_rate=outage,

@@ -398,16 +398,11 @@ class TestJsonWriter:
         assert payload["elapsed_s"] == 1.234
         assert payload["rows"][1] == {"name": "b", "value": 2.5,
                                       "derived": "y"}
-        # the active engines are stamped next to workers/devices so
-        # nightly refreshes can tell configuration trends apart
+        # the planner engine is stamped next to workers/devices so
+        # nightly refreshes can tell configuration trends apart; the
+        # denoising session has one engine, so nothing names it
         assert payload["engine"] in ("vec", "scalar", "jax")
-        assert payload["exec_engine"] in ("dict", "bucketed")
-
-    def test_write_json_exec_engine_env(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("REPRO_EXEC_ENGINE", "bucketed")
-        path = bench_run.write_json(tmp_path / "out", "demo",
-                                    [], 0.1, "cafebabe")
-        assert json.loads(path.read_text())["exec_engine"] == "bucketed"
+        assert "exec_engine" not in payload
 
     def test_git_sha_is_nonempty(self):
         assert bench_run.git_sha()

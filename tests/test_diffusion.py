@@ -128,6 +128,28 @@ class TestExecutor:
         np.testing.assert_allclose(imgs[0], np.asarray(want[0]),
                                    atol=1e-3, rtol=1e-3)
 
+    def test_stacking_plan_matches_plain_sampling_per_service(
+            self, unet_params):
+        """A five-service STACKING plan on the served session: each
+        service's image is plain DDIM sampling at its own T, from its
+        own key of ``split(key, 5)`` in sorted-id order."""
+        scn = make_scenario(K=5, tau_min=2, tau_max=6, seed=1)
+        tp = tau_prime_of(scn, inv_se_allocate(scn))
+        plan = stacking(scn.services, tp, DelayModel(), PowerLawFID())
+        ids = sorted(plan.steps_completed)
+        assert len({plan.steps_completed[k] for k in ids}) >= 2
+        ex = BatchDenoisingExecutor(SMOKE, unet_params)
+        key = jax.random.PRNGKey(17)
+        imgs, _ = ex.run(plan, key)
+        eps_fn = lambda x, t: unet.forward(SMOKE, unet_params, x, t)
+        keys = jax.random.split(key, len(ids))
+        for i, k in enumerate(ids):
+            want = ddim.sample(eps_fn, keys[i], (1, 16, 16, 3),
+                               plan.steps_completed[k])
+            np.testing.assert_allclose(imgs[k], np.asarray(want[0]),
+                                       atol=1e-3, rtol=1e-3,
+                                       err_msg=f"service {k}")
+
     def test_multi_service_plan_executes_all(self, unet_params):
         delay, quality = DelayModel(), PowerLawFID()
         scn = make_scenario(K=5, tau_min=2, tau_max=6, seed=1)

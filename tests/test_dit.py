@@ -103,7 +103,7 @@ def test_bucketed_session_matches_reference_chains(params):
     tau = {s.id: s.deadline for s in scn.services}
     plan = stacking(scn.services, tau, DelayModel(a=0.25, b=1.0),
                     PowerLawFID())
-    wl = DiffusionWorkload(cfg=SMALL, params=params, exec_engine="bucketed")
+    wl = DiffusionWorkload(cfg=SMALL, params=params)
     key = jax.random.PRNGKey(5)
     sess = wl.open_session(plan, key)
     events = []
@@ -130,7 +130,7 @@ def test_bucketed_session_matches_reference_chains(params):
 def test_closed_loop_through_the_provisioner():
     """DiT runs through the front door as the U-Net does; with no
     params the workload builds them from DiT's schema."""
-    wl = DiffusionWorkload(cfg=SMALL, exec_engine="bucketed")
+    wl = DiffusionWorkload(cfg=SMALL)
     scn = make_scenario(K=4, seed=6)
     rep = Provisioner(scn, workload=wl, scheduler="stacking_offset",
                       allocator="inv_se", delay=DelayModel(a=0.3, b=1.0),
@@ -211,7 +211,7 @@ def test_attention_path_engages_the_whole_sequence_kernel(monkeypatch):
     import repro.kernels
     monkeypatch.setattr(repro.kernels, "use_pallas", lambda: "tpu")
     params = abstract_params(dit.schema(DIT_XL_2), jnp.float32)
-    ex = BatchDenoisingExecutor(DIT_XL_2, params, exec_engine="bucketed")
+    ex = BatchDenoisingExecutor(DIT_XL_2, params)
     plan = BatchPlan(batches=[], start_times=[],
                      steps_completed={k: 20 for k in range(32)},
                      delay=DelayModel(a=0.0034, b=0.0007))

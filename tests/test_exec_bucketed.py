@@ -1,9 +1,10 @@
-"""Device-resident bucketed denoising engine vs the dict reference.
+"""The served denoising session (the device-resident pool) vs the
+per-row reference session.
 
-Contract (docs/PERFORMANCE.md): per-row results match the dict engine
-within ``MATCH_TOL`` — padded-width XLA programs may fuse differently
-from exact-width ones, so bit identity across engines is not promised
-(the dict path stays the bit-exact-per-row reference).  The property
+Contract (docs/PERFORMANCE.md): per-row results match
+``ReferenceDenoiseSession`` within ``MATCH_TOL`` — padded-width XLA
+programs may fuse differently from exact-width ones, so bit identity
+across the two is not promised.  The property
 test generates arbitrary plan shapes; the fixed-plan tests pin the
 scheduling edge cases (retarget mid-scan, composition breaks, zero-step
 services) and the compile economics (≤ ⌈log2 K⌉ step programs, warm
@@ -21,15 +22,16 @@ pytest.importorskip("jax")
 import jax
 import jax.numpy as jnp
 
+from repro.api import DiffusionWorkload
 from repro.configs.ddim_cifar10 import UNetConfig
 from repro.core.delay_model import DelayModel
-from repro.core.execution import (EXEC_ENGINES, exec_engine_default,
-                                  shape_bucket)
+from repro.core.execution import shape_bucket
 from repro.core.plan import BatchPlan
 from repro.diffusion import unet
-from repro.diffusion.bucketed import (MATCH_TOL, BucketedDenoiseSession,
-                                      _SCAN_CHUNKS)
-from repro.diffusion.executor import BatchDenoisingExecutor
+from repro.diffusion.executor import (_SCAN_CHUNKS, BatchDenoisingExecutor,
+                                      DenoiseSession)
+from repro.diffusion.reference import (MATCH_TOL, ReferenceDenoiseSession,
+                                       reference_images)
 from repro.models.params import init_params
 
 try:
@@ -94,8 +96,8 @@ class TestEnginesMatch:
     def test_fixed_plans(self, ex, counts):
         plan = make_plan(counts, stacking_batches(counts))
         key = jax.random.PRNGKey(42)
-        want, _ = ex.run(plan, key, exec_engine="dict")
-        got, _ = ex.run(plan, key, exec_engine="bucketed")
+        want = reference_images(ex, plan, key)
+        got, _ = ex.run(plan, key)
         assert_rows_match(got, want)
 
     def test_timed_matches_untimed_bucketed(self, ex):
@@ -104,17 +106,17 @@ class TestEnginesMatch:
         counts = {0: 4, 1: 4, 2: 2}
         plan = make_plan(counts, stacking_batches(counts))
         key = jax.random.PRNGKey(7)
-        plain, no_t = ex.run(plan, key, exec_engine="bucketed")
-        timed, ts = ex.run(plan, key, timed=True, exec_engine="bucketed")
+        plain, no_t = ex.run(plan, key)
+        timed, ts = ex.run(plan, key, timed=True)
         assert no_t == [] and len(ts) == plan.num_batches
         assert_rows_match(timed, plain)
 
     def test_zero_step_latent_untouched(self, ex):
-        """Parity with the dict regression: a service the planner
-        retired at T=0 comes back as its seeded noise, exactly."""
+        """A service the planner retired at T=0 comes back as its
+        seeded noise, exactly."""
         plan = make_plan({0: 0, 1: 2}, [[1], [1]])
         key = jax.random.PRNGKey(13)
-        imgs, _ = ex.run(plan, key, exec_engine="bucketed")
+        imgs, _ = ex.run(plan, key)
         k0 = jax.random.split(key, 2)[0]
         raw = jax.random.normal(k0, (8, 8, 3), jnp.float32)
         np.testing.assert_array_equal(imgs[0], np.asarray(raw))
@@ -127,7 +129,7 @@ class TestEnginesMatch:
                seed=st.integers(0, 2 ** 16 - 1))
         def test_property_bucket_equals_dict(self, ex, counts, drop,
                                              seed):
-            """Arbitrary plan shapes: per-row bucketed == dict within
+            """Arbitrary plan shapes: per-row served == reference within
             MATCH_TOL.  ``drop`` perturbs the all-active composition by
             deferring one service's steps, so compositions mix."""
             counts = {k: c for k, c in enumerate(counts)}
@@ -139,24 +141,23 @@ class TestEnginesMatch:
             batches += [[victim]] * deferred
             plan = make_plan(counts, batches)
             key = jax.random.PRNGKey(seed)
-            want, _ = ex.run(plan, key, exec_engine="dict")
-            got, _ = ex.run(plan, key, exec_engine="bucketed")
+            want = reference_images(ex, plan, key)
+            got, _ = ex.run(plan, key)
             assert_rows_match(got, want)
 
 
 class TestScheduling:
     def test_retarget_mid_scan(self, ex):
         """Retargeting between run_plan calls (i.e. between fused scan
-        megasteps) lands on the same images as the dict session driven
-        identically."""
+        megasteps) lands on the same images as the reference session
+        driven identically."""
         counts = {0: 6, 1: 6, 2: 6}
         key = jax.random.PRNGKey(3)
-        sessions = [ex.open_session(make_plan(counts,
-                                              stacking_batches(counts)),
-                                    key, exec_engine=e)
-                    for e in ("dict", "bucketed")]
+        plan = make_plan(counts, stacking_batches(counts))
+        sessions = [ReferenceDenoiseSession(ex, plan, key),
+                    ex.open_session(plan, key)]
         for sess in sessions:
-            sess.run_plan([[0, 1, 2]] * 3)        # fused on bucketed
+            sess.run_plan([[0, 1, 2]] * 3)        # fused on the pool
             sess.retarget({0: 4, 1: 8})           # shrink / stretch
             sess.run_batch([0, 1, 2])
             sess.run_plan([[1, 2]] * 2)           # 0 retired at 4
@@ -171,24 +172,22 @@ class TestScheduling:
         counts = {0: 5, 1: 4, 2: 2}
         batches = [[0, 1], [0, 1], [0, 1], [0, 2], [0, 2], [1]]
         plan = make_plan(counts, batches)
-        sess = ex.open_session(plan, jax.random.PRNGKey(9),
-                               exec_engine="bucketed")
+        sess = ex.open_session(plan, jax.random.PRNGKey(9))
         sess.run_plan([list(b) for b in batches])
         tele = sess.telemetry()
         # [0,1]x3 -> scan(2)+step; [0,2]x2 -> scan(2); [1] -> step
         assert tele["scan_fused_steps"] == 4
         assert tele["scan_dispatches"] == {"b2_c2": 2}
         assert tele["by_bucket"] == {"2": 2}
-        # and the images still match the dict path
-        want, _ = ex.run(plan, jax.random.PRNGKey(9), exec_engine="dict")
+        # and the images still match the reference
+        want = reference_images(ex, plan, jax.random.PRNGKey(9))
         assert_rows_match(sess.finish(), want)
 
     def test_retarget_errors_preserved(self, ex):
-        """The inherited no-resurrection rules hold on the pool path."""
+        """The shared no-resurrection rules hold on the pool path."""
         counts = {0: 3, 1: 3}
         plan = make_plan(counts, stacking_batches(counts))
-        sess = ex.open_session(plan, jax.random.PRNGKey(1),
-                               exec_engine="bucketed")
+        sess = ex.open_session(plan, jax.random.PRNGKey(1))
         sess.run_batch([0, 1])
         with pytest.raises(ValueError, match="already executed"):
             sess.retarget({0: 0})
@@ -206,8 +205,7 @@ class TestCompileEconomics:
         K = 8
         counts = {k: k + 1 for k in range(K)}    # sizes 8,7,...,1
         plan = make_plan(counts, stacking_batches(counts))
-        sess = fresh.open_session(plan, jax.random.PRNGKey(2),
-                                  exec_engine="bucketed")
+        sess = fresh.open_session(plan, jax.random.PRNGKey(2))
         for b in plan.batches:                   # stepwise: no scans
             sess.run_batch([k for k, _ in b])
         steps = [k for k, _ in fresh.compile_log if k[0] == "bstep"]
@@ -218,9 +216,8 @@ class TestCompileEconomics:
     def test_second_session_is_warm(self, ex):
         counts = {0: 2, 1: 2, 2: 1}
         plan = make_plan(counts, stacking_batches(counts))
-        ex.run(plan, jax.random.PRNGKey(4), exec_engine="bucketed")
-        sess = ex.open_session(plan, jax.random.PRNGKey(5),
-                               exec_engine="bucketed")
+        ex.run(plan, jax.random.PRNGKey(4))
+        sess = ex.open_session(plan, jax.random.PRNGKey(5))
         sess.run_plan(stacking_batches(counts))
         tele = sess.telemetry()
         assert tele["compiles"] == 0 and tele["compile_s"] == 0.0
@@ -233,7 +230,7 @@ class TestCompileEconomics:
         fresh = BatchDenoisingExecutor(MICRO, params)
         curve = fresh.measure_delay_curve(jax.random.PRNGKey(6),
                                           batch_sizes=range(1, 9),
-                                          reps=2, exec_engine="bucketed")
+                                          reps=2)
         assert [x for x, _ in curve] == list(range(1, 9))
         assert len(fresh.last_compile_log) == 3      # buckets 2, 4, 8
         # same-bucket sizes pay the same padded cost; readings are
@@ -243,26 +240,37 @@ class TestCompileEconomics:
 
 
 class TestEngineKnob:
-    def test_registry_and_default(self, monkeypatch):
-        assert EXEC_ENGINES == ("dict", "bucketed")
-        monkeypatch.delenv("REPRO_EXEC_ENGINE", raising=False)
-        assert exec_engine_default() == "dict"
-        monkeypatch.setenv("REPRO_EXEC_ENGINE", "bucketed")
-        assert exec_engine_default() == "bucketed"
-
-    def test_env_default_opens_bucketed(self, ex, monkeypatch):
-        monkeypatch.setenv("REPRO_EXEC_ENGINE", "bucketed")
+    def test_registry_and_default(self, ex):
+        """``open_session`` opens the pool session, and its telemetry
+        says so."""
         plan = make_plan({0: 1}, [[0]])
         sess = ex.open_session(plan, jax.random.PRNGKey(0))
-        assert isinstance(sess, BucketedDenoiseSession)
+        assert isinstance(sess, DenoiseSession)
+        assert sess.telemetry()["exec_engine"] == "bucketed"
 
-    def test_unknown_engine_rejected(self, ex):
-        plan = make_plan({0: 1}, [[0]])
+    def test_unknown_engine_rejected(self):
+        """The workload's compatibility keyword takes only the pool."""
+        DiffusionWorkload(cfg=MICRO, exec_engine="bucketed")
         with pytest.raises(ValueError, match="unknown exec_engine"):
-            ex.open_session(plan, jax.random.PRNGKey(0),
-                            exec_engine="gpu")
-        with pytest.raises(ValueError, match="unknown exec_engine"):
-            BatchDenoisingExecutor(MICRO, ex.params, exec_engine="gpu")
+            DiffusionWorkload(cfg=MICRO, exec_engine="dict")
+
+    def test_pool_rows_are_the_per_service_draws(self, ex):
+        """The seeding contract: pool row i is
+        ``normal(split(key, K)[i])`` for the i-th service in sorted-id
+        order, bit for bit, and the scratch row is zero."""
+        plan = make_plan({7: 2, 3: 1, 11: 0, 5: 1}, [[7, 3, 5], [7]])
+        key = jax.random.PRNGKey(21)
+        sess = ex.open_session(plan, key)
+        pool = np.asarray(sess._pool)
+        ids = sorted(plan.steps_completed)
+        keys = jax.random.split(key, len(ids))
+        assert pool.shape == (len(ids) + 1, 8, 8, 3)
+        for i, k in enumerate(ids):
+            np.testing.assert_array_equal(
+                pool[i],
+                np.asarray(jax.random.normal(keys[i], (8, 8, 3),
+                                             jnp.float32)))
+        np.testing.assert_array_equal(pool[-1], np.zeros((8, 8, 3)))
 
     def test_shape_bucket_grid(self):
         assert [shape_bucket(n) for n in (1, 2, 3, 4, 5, 8, 9, 16)] == \

@@ -3,7 +3,7 @@
 Two provisioning rounds run under one ``jax.profiler.trace``: one on the
 ``simulated`` executor with a planner twice too fast, so the closed loop
 replans through the offset-native search, and one on the SMOKE U-Net's
-``bucketed`` engine with a fresh executor, so its step programs compile
+served session with a fresh executor, so its step programs compile
 inside the round.  The trace is read with ``jax.profiler.ProfileData``.
 """
 
@@ -92,12 +92,12 @@ def traced(tmp_path_factory):
         execute_kwargs={"executor": "simulated",
                         "executor_kwargs": {"true_delay": TRUE},
                         "min_batches": 2, "drift_tol": 0.2})
-    wl = DiffusionWorkload(cfg=SMOKE, exec_engine="bucketed")
+    wl = DiffusionWorkload(cfg=SMOKE)
     net = Provisioner(
         make_scenario(K=3, seed=0), workload=wl,
         scheduler="stacking_offset", allocator="inv_se",
         delay=DelayModel(a=0.002, b=0.02),
-        execute_kwargs={"exec_engine": "bucketed", "max_replans": 2})
+        execute_kwargs={"max_replans": 2})
     ex = wl.executor
     with jax.profiler.trace(d):
         reports = [sim.run(jax.random.PRNGKey(0), execute="closed")]

@@ -27,8 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.ddim_cifar10 import CONFIG, DIT_XL_2
 from repro.diffusion import dit, unet
-from repro.diffusion.bucketed import pool_step
-from repro.diffusion.executor import BatchDenoisingExecutor
+from repro.diffusion.executor import BatchDenoisingExecutor, pool_step
 from repro.kernels.groupnorm_silu.kernel import groupnorm_silu_pallas
 from repro.models.params import abstract_params, init_params
 
@@ -114,7 +113,7 @@ def test_bucketed_step_compiles_for_v5e(one_chip, monkeypatch):
     params = _param_shapes(one_chip)
     n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
     assert 35e6 < n_params < 36e6
-    ex = BatchDenoisingExecutor(CONFIG, params, exec_engine="bucketed")
+    ex = BatchDenoisingExecutor(CONFIG, params)
     rows = BUCKET + 1
     shape = (CONFIG.image_size, CONFIG.image_size, CONFIG.in_channels)
     pool = jax.ShapeDtypeStruct((rows,) + shape, jnp.float32,
@@ -141,7 +140,7 @@ def test_dit_bucketed_step_compiles_for_v5e(one_chip, monkeypatch):
         abstract_params(dit.schema(DIT_XL_2), jnp.float32))
     n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
     assert 668e6 < n_params < 682e6
-    ex = BatchDenoisingExecutor(DIT_XL_2, params, exec_engine="bucketed")
+    ex = BatchDenoisingExecutor(DIT_XL_2, params)
     rows = DIT_BUCKET + 1
     shape = (DIT_XL_2.image_size, DIT_XL_2.image_size, DIT_XL_2.in_channels)
     pool = jax.ShapeDtypeStruct((rows,) + shape, jnp.float32,
